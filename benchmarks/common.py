@@ -14,7 +14,10 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import use_compile_cache
+
 jax.config.update("jax_enable_x64", True)
+use_compile_cache()
 
 BENCH_N = int(os.environ.get("REPRO_BENCH_N", "1200"))
 

@@ -87,7 +87,6 @@ def _bench_rbf_matvec_1e5():
     from repro.core import sharded
     from repro.core.operators import RBFKernelSystemOperator
     from repro.launch.mesh import make_solve_mesh
-    from jax.experimental.shard_map import shard_map
 
     n = 100_000
     if jax.device_count() < 8:
@@ -111,12 +110,12 @@ def _bench_rbf_matvec_1e5():
         return apply(v_loc)
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             one_matvec,
             mesh=mesh,
             in_specs=(leaf_specs, P("solve")),
             out_specs=P("solve"),
-            check_rep=False,
+            check_vma=False,
         )
     )
     v_sh = jax.device_put(v, NamedSharding(mesh, P("solve")))

@@ -83,7 +83,6 @@ class LintConfig:
     host_side_allowlist: Tuple[str, ...] = (
         "checkpoint/",
         "faults.py",  # io_callback-based chaos instrumentation (host-counted)
-        "tpu_compat.py",
     )
     ops_module: str = "kernels/ops.py"
     ref_module_name: str = "ref"

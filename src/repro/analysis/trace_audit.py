@@ -118,7 +118,7 @@ def count_compiles() -> Iterator[_CompileCapture]:
 
 
 def _subjaxprs(value):
-    import jax.core as jcore
+    from jax.extend import core as jcore
 
     if isinstance(value, jcore.ClosedJaxpr):
         yield value.jaxpr
@@ -129,14 +129,38 @@ def _subjaxprs(value):
             yield from _subjaxprs(v)
 
 
-def iter_primitives(jaxpr) -> Iterator[str]:
-    """Every primitive name in ``jaxpr``, recursing into sub-jaxprs
-    (scan/while/cond bodies, pjit calls)."""
+def iter_eqns(jaxpr) -> Iterator:
+    """Every equation in ``jaxpr``, recursing into sub-jaxprs
+    (scan/while/cond bodies, pjit calls, shard_map and Pallas kernels)."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
+        yield eqn
         for v in eqn.params.values():
             for sub in _subjaxprs(v):
-                yield from iter_primitives(sub)
+                yield from iter_eqns(sub)
+
+
+def iter_primitives(jaxpr) -> Iterator[str]:
+    """Every primitive name in ``jaxpr`` (see :func:`iter_eqns`)."""
+    for eqn in iter_eqns(jaxpr):
+        yield eqn.primitive.name
+
+
+def reduced_precision_dots(closed_jaxpr) -> List[str]:
+    """The ``dot_general``s that do not ask for ``Precision.HIGHEST``.
+
+    On a TPU such a contraction of f32 operands runs as one bf16 pass.
+    """
+    import jax
+
+    full = (jax.lax.Precision.HIGHEST,) * 2
+    return [
+        "dot_general("
+        + ", ".join(v.aval.str_short() for v in eqn.invars)
+        + f") precision={eqn.params['precision']}"
+        for eqn in iter_eqns(closed_jaxpr.jaxpr)
+        if eqn.primitive.name == "dot_general"
+        and eqn.params["precision"] != full
+    ]
 
 
 def find_forbidden(closed_jaxpr) -> List[str]:

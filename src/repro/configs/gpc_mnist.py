@@ -25,7 +25,7 @@ class GPCConfig:
     newton_tol: float = 1.0
     max_newton: int = 12
     dtype: str = "float32"
-    block: int = 1024           # fused-matvec row block
+    block: int = 256            # fused-matvec tile; 512 overflows v5e VMEM
 
 
 CONFIG = GPCConfig()

@@ -104,7 +104,7 @@ def _factor_wnw(w_flat, nw_flat, k: int, jitter: float):
     ``WᵀAW`` factor: relative diagonal jitter, plus UNconditional
     regularization of exactly-zero columns (clamped extraction slots /
     cold states deflate as exact no-ops; see ``solvers._factor_waw``)."""
-    wnw = w_flat @ nw_flat.T
+    wnw = pt.matmul(w_flat, nw_flat.T)
     wnw = 0.5 * (wnw + wnw.T)
     dj = jnp.diag(wnw)
     tr = jnp.sum(dj)
@@ -194,11 +194,11 @@ def lsmr(
 
         def q_apply(vv):
             # Right projection: N-orthogonalize against W.
-            return vv - (winv @ (nw_flat @ vv)) @ W
+            return vv - pt.matmul(pt.matmul(winv, pt.matmul(nw_flat, vv)), W)
 
         def qt_apply(gg):
             # Its transpose, applied to adjoint products.
-            return gg - (winv @ (W @ gg)) @ nw_flat
+            return gg - pt.matmul(pt.matmul(winv, pt.matmul(W, gg)), nw_flat)
     else:
         q_apply = qt_apply = lambda z: z  # noqa: E731
 
@@ -211,9 +211,9 @@ def lsmr(
         r_m = b_flat
     u_n0 = -sqrt_damp * x_flat if has_shift else None
 
-    beta_sq = jnp.vdot(r_m, r_m)
+    beta_sq = pt.vdot(r_m, r_m)
     if has_shift:
-        beta_sq = beta_sq + jnp.vdot(u_n0, u_n0)
+        beta_sq = beta_sq + pt.vdot(u_n0, u_n0)
     beta1 = jnp.sqrt(beta_sq)
     safe_b = jnp.where(beta1 == 0.0, 1.0, beta1)
     u_m0 = r_m / safe_b
@@ -223,7 +223,7 @@ def lsmr(
     if has_shift:
         g0 = g0 + sqrt_damp * u_n0
     g0 = qt_apply(g0)
-    alpha1 = jnp.sqrt(jnp.vdot(g0, g0))
+    alpha1 = jnp.sqrt(pt.vdot(g0, g0))
     safe_a = jnp.where(alpha1 == 0.0, 1.0, alpha1)
     v0 = g0 / safe_a
 
@@ -260,10 +260,10 @@ def lsmr(
         else:
             av = A_flat(qv)
         u_m_new = av - alpha * u_m
-        beta_sq_ = jnp.vdot(u_m_new, u_m_new)
+        beta_sq_ = pt.vdot(u_m_new, u_m_new)
         if has_shift:
             u_n_new = sqrt_damp * qv - alpha * u_n
-            beta_sq_ = beta_sq_ + jnp.vdot(u_n_new, u_n_new)
+            beta_sq_ = beta_sq_ + pt.vdot(u_n_new, u_n_new)
         beta_new = jnp.sqrt(beta_sq_)
         sb = jnp.where(beta_new == 0.0, 1.0, beta_new)
         u_m_new = u_m_new / sb
@@ -283,7 +283,7 @@ def lsmr(
         #   N̂ v_j = B̂ᵀB̂ v_j = α_j·B̂ᵀu_j + β_{j+1}·B̂ᵀu_{j+1}.
         nv = alpha * g + beta_new * g_new
         w_vec = g_new - beta_new * v
-        alpha_new = jnp.sqrt(jnp.vdot(w_vec, w_vec))
+        alpha_new = jnp.sqrt(pt.vdot(w_vec, w_vec))
         sa = jnp.where(alpha_new == 0.0, 1.0, alpha_new)
         v_new = w_vec / sa
 
@@ -511,8 +511,8 @@ def _one_recycled_lsmr(
     if damp > 0.0:
         s0 = s0 - damp * x_prev
     wnw_cho = _factor_wnw(w, nw_used, k, waw_jitter)
-    cvec = cho_solve(wnw_cho, w @ s0)
-    x0p = x_prev + cvec @ w
+    cvec = cho_solve(wnw_cho, pt.matmul(w, s0))
+    x0p = x_prev + pt.matmul(cvec, w)
     guess_charge = jnp.int32(2)
 
     result = lsmr(

@@ -186,10 +186,10 @@ class DenseMatrixOperator(LinearOperator):
         m, n = (shape[-2], shape[-1]) if shape and len(shape) >= 2 else (0, 0)
 
         def mv(v):
-            return mat @ v
+            return pt.matmul(mat, v)
 
         def rmv(u):
-            return jnp.swapaxes(mat, -2, -1) @ u
+            return pt.matmul(jnp.swapaxes(mat, -2, -1), u)
 
         LinearOperator.__init__(
             self, mv, matvec_cost_flops=2.0 * m * n, matmat=mv, rmatvec=rmv

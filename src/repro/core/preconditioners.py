@@ -110,9 +110,9 @@ class WoodburyKernelPreconditioner:
     lower: bool = dataclasses.field(default=False)
 
     def __call__(self, r: jnp.ndarray) -> jnp.ndarray:
-        t = self.U @ (self.sqrt_h * r)
+        t = pt.matmul(self.U, self.sqrt_h * r)
         s = cho_solve((self.chol_c, self.lower), t)
-        return r - self.sqrt_h * (s @ self.U)
+        return r - self.sqrt_h * pt.matmul(s, self.U)
 
     def tree_flatten(self):
         return (self.sqrt_h, self.U, self.chol_c), (self.lower,)
@@ -137,7 +137,7 @@ def kernel_nystrom_preconditioner(
     U = pt.ravel_basis(U) if not isinstance(U, jnp.ndarray) or U.ndim != 2 else U
     lam_floor = 1e-12 * jnp.maximum(jnp.max(lam), 1.0)
     lam_safe = jnp.maximum(lam, lam_floor)
-    uhu = (U * (sqrt_h * sqrt_h)[None, :]) @ U.T
+    uhu = pt.matmul(U * (sqrt_h * sqrt_h)[None, :], U.T)
     C = jnp.diag(1.0 / lam_safe) + uhu
     C = 0.5 * (C + C.T)
     chol, lower = cho_factor(C)

@@ -87,6 +87,23 @@ def tree_random_like(key, a: Pytree, dtype=None) -> Pytree:
     return jax.tree_util.tree_unflatten(treedef, new)
 
 
+# Every contraction in the solvers runs at full f32 precision.  The TPU
+# default for f32 operands is one bf16 pass (~3 significant digits): the
+# deflation coefficients μ = (WᵀAW)⁻¹(AW)ᵀr then miss the solve's tolerance
+# and a recycled def-CG stalls.  On CPU the flag changes nothing.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a @ b`` at full f32 precision (see :data:`HIGHEST`)."""
+    return jnp.matmul(a, b, precision=HIGHEST)
+
+
+def vdot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``aᵀb`` of two flat vectors at full f32 precision."""
+    return jnp.vdot(a, b, precision=HIGHEST)
+
+
 def _acc_dtype(dtype):
     """Accumulation dtype: keep f64 as f64, promote everything real to f32+."""
     if dtype == jnp.float64:
@@ -172,9 +189,9 @@ def basis_dot(basis: Pytree, v: Pytree) -> jnp.ndarray:
 
     def leaf_dot(bl, vl):
         m = bl.shape[0]
-        return (
-            bl.reshape(m, -1).astype(_acc_dtype(bl.dtype))
-            @ vl.reshape(-1).astype(_acc_dtype(vl.dtype))
+        return matmul(
+            bl.reshape(m, -1).astype(_acc_dtype(bl.dtype)),
+            vl.reshape(-1).astype(_acc_dtype(vl.dtype)),
         )
 
     leaves = jax.tree_util.tree_leaves(
@@ -188,8 +205,9 @@ def basis_combine(basis: Pytree, coef: jnp.ndarray) -> Pytree:
 
     def leaf_comb(bl):
         m = bl.shape[0]
-        flat = coef.astype(_acc_dtype(bl.dtype)) @ bl.reshape(m, -1).astype(
-            _acc_dtype(bl.dtype)
+        flat = matmul(
+            coef.astype(_acc_dtype(bl.dtype)),
+            bl.reshape(m, -1).astype(_acc_dtype(bl.dtype)),
         )
         return flat.reshape(bl.shape[1:]).astype(bl.dtype)
 
@@ -201,8 +219,9 @@ def basis_matmul(basis: Pytree, mat: jnp.ndarray) -> Pytree:
 
     def leaf_mm(bl):
         m = bl.shape[0]
-        flat = mat.T.astype(_acc_dtype(bl.dtype)) @ bl.reshape(m, -1).astype(
-            _acc_dtype(bl.dtype)
+        flat = matmul(
+            mat.T.astype(_acc_dtype(bl.dtype)),
+            bl.reshape(m, -1).astype(_acc_dtype(bl.dtype)),
         )
         return flat.reshape((mat.shape[1],) + bl.shape[1:]).astype(bl.dtype)
 
@@ -214,9 +233,10 @@ def gram(a: Pytree, b: Pytree) -> jnp.ndarray:
 
     def leaf_gram(al, bl):
         ma, mb = al.shape[0], bl.shape[0]
-        return al.reshape(ma, -1).astype(_acc_dtype(al.dtype)) @ bl.reshape(
-            mb, -1
-        ).astype(_acc_dtype(bl.dtype)).T
+        return matmul(
+            al.reshape(ma, -1).astype(_acc_dtype(al.dtype)),
+            bl.reshape(mb, -1).astype(_acc_dtype(bl.dtype)).T,
+        )
 
     leaves = jax.tree_util.tree_leaves(jax.tree_util.tree_map(leaf_gram, a, b))
     return functools.reduce(jnp.add, leaves)

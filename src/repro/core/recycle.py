@@ -187,14 +187,14 @@ def harmonic_ritz(
     rcond = jnp.maximum(jnp.asarray(jitter, G.dtype), 100.0 * eps) * m
     good = lam > rcond * lam[-1]
     s = jnp.where(good, 1.0 / jnp.sqrt(jnp.maximum(lam, 1e-300)), 0.0)
-    M = s[:, None] * (qg.T @ F @ qg) * s[None, :]
+    M = s[:, None] * pt.matmul(pt.matmul(qg.T, F), qg) * s[None, :]
     M = 0.5 * (M + M.T)
     zeta, Wm = jnp.linalg.eigh(M)  # ascending ζ = 1/θ
 
     w_sel, theta, slot_ok = _select_positive_ritz(zeta, Wm, k, select)
 
     # u = D · Qg S w  (undo reduction and equilibration).
-    u = qg @ (s[:, None] * w_sel)
+    u = pt.matmul(qg, s[:, None] * w_sel)
     u = u * d[:, None]
 
     W = pt.basis_matmul(Z, u)

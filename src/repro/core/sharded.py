@@ -5,7 +5,7 @@ an iteration (``x, r, p, z``), the ``(k, n)`` recycled-basis leaves of
 :class:`repro.core.recycle.RecycleState`, and the recorded ``(ell, n)``
 window rows are sharded along the coordinate dimension over a 1-D
 ``"solve"`` mesh axis, and the def-CG / CG / LSMR loop harnesses run
-under :func:`jax.experimental.shard_map.shard_map` with the fused kernel
+under :func:`jax.shard_map` with the fused kernel
 ops (:mod:`repro.kernels.ops`) applied per-shard.
 
 The communication contract is ONE collective per def-CG iteration: all
@@ -68,7 +68,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.scipy.linalg import cho_factor, cho_solve
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
@@ -211,21 +210,21 @@ def _make_applies(kind: str, aux, leaves):
 
         def apply(v_loc):
             v_full = jax.lax.all_gather(v_loc, ax, tiled=True)
-            return mat_loc @ v_full
+            return pt.matmul(mat_loc, v_full)
 
         if len(leaves) > 1:
             mat_t_loc = leaves[1]
 
             def rapply(u_loc):
                 u_full = jax.lax.all_gather(u_loc, ax, tiled=True)
-                return mat_t_loc @ u_full
+                return pt.matmul(mat_t_loc, u_full)
 
         else:
             rapply = apply
 
         def basis_apply(w_loc):  # (k, n_loc) -> (k, n_loc)
             w_full = jax.lax.all_gather(w_loc, ax, axis=1, tiled=True)
-            return w_full @ mat_loc.T
+            return pt.matmul(w_full, mat_loc.T)
 
         return apply, rapply, basis_apply
 
@@ -278,7 +277,7 @@ def _sharded_cg_body(
         apply, _, _ = _make_applies(kind, aux, leaves)
         r0 = b_loc - apply(x0_loc)
         bsq, rs0 = engine.psum_merged(
-            [jnp.vdot(b_loc, b_loc), jnp.vdot(r0, r0)], ax
+            [pt.vdot(b_loc, b_loc), pt.vdot(r0, r0)], ax
         )
         bnorm = jnp.sqrt(bsq)
         threshold = jnp.maximum(tol * bnorm, atol)
@@ -297,8 +296,8 @@ def _sharded_cg_body(
             ap = apply(p)
             d, rap, apap, rs = engine.psum_merged(
                 [
-                    jnp.vdot(p, ap), jnp.vdot(r, ap),
-                    jnp.vdot(ap, ap), jnp.vdot(r, r),
+                    pt.vdot(p, ap), pt.vdot(r, ap),
+                    pt.vdot(ap, ap), pt.vdot(r, r),
                 ],
                 ax,
             )
@@ -401,7 +400,9 @@ def _sharded_defcg_body(
         r_init = b_loc - apply(x0_loc)
         matvecs = matvecs + 1
         waw, bsq, wr = engine.psum_merged(
-            [w_loc @ aw_used.T, jnp.vdot(b_loc, b_loc), w_loc @ r_init], ax
+            [pt.matmul(w_loc, aw_used.T), pt.vdot(b_loc, b_loc),
+             pt.matmul(w_loc, r_init)],
+            ax,
         )
         bnorm = jnp.sqrt(bsq)
         threshold = jnp.maximum(tol * bnorm, atol)
@@ -419,11 +420,11 @@ def _sharded_defcg_body(
         waw_cho = cho_factor(waw)
 
         c = cho_solve(waw_cho, wr)
-        x = x0_loc + c @ w_loc
-        r = r_init - c @ aw_used
-        rs0, awr0 = engine.psum_merged([jnp.vdot(r, r), aw_used @ r], ax)
+        x = x0_loc + pt.matmul(c, w_loc)
+        r = r_init - pt.matmul(c, aw_used)
+        rs0, awr0 = engine.psum_merged([pt.vdot(r, r), pt.matmul(aw_used, r)], ax)
         mu0 = cho_solve(waw_cho, awr0)
-        p0 = r - mu0 @ w_loc
+        p0 = r - pt.matmul(mu0, w_loc)
         winv = cho_solve(waw_cho, jnp.eye(k, dtype=aw_used.dtype))
         rnorm0 = jnp.sqrt(rs0)
 
@@ -444,7 +445,7 @@ def _sharded_defcg_body(
             rap_l, awap_l = kops.fused_rz_reduce(r, ap, aw_used)
             rs_l, awr_l = kops.fused_rz_reduce(r, r, aw_used)
             d, rap, apap, awap, rs, awr = engine.psum_merged(
-                [jnp.vdot(p, ap), rap_l, jnp.vdot(ap, ap), awap_l,
+                [pt.vdot(p, ap), rap_l, pt.vdot(ap, ap), awap_l,
                  rs_l, awr_l],
                 ax,
             )
@@ -464,7 +465,7 @@ def _sharded_defcg_body(
                 rs - 2.0 * alpha * rap + alpha * alpha * apap, 0.0
             )
             awr_new = awr - alpha * awap
-            mu = winv @ awr_new.astype(winv.dtype)
+            mu = pt.matmul(winv, awr_new.astype(winv.dtype))
             beta = rs_new / jnp.where(rs == 0.0, 1.0, rs)
             p_new, _, _ = kops.fused_deflate_direction(
                 r, p, beta, w_loc, mu
@@ -582,9 +583,9 @@ def _sharded_lsmr_body(
             r_m = b_loc
         u_n0 = -sqrt_damp * x0_loc if has_shift else None
 
-        bsum = jnp.vdot(r_m, r_m)
+        bsum = pt.vdot(r_m, r_m)
         if has_shift:
-            bsum = bsum + jnp.vdot(u_n0, u_n0)
+            bsum = bsum + pt.vdot(u_n0, u_n0)
         (beta_sq,) = engine.psum_merged([bsum], ax)
         beta1 = jnp.sqrt(beta_sq)
         safe_b = jnp.where(beta1 == 0.0, 1.0, beta1)
@@ -594,7 +595,7 @@ def _sharded_lsmr_body(
         g0 = rapply(u_m0)
         if has_shift:
             g0 = g0 + sqrt_damp * u_n0
-        (asum,) = engine.psum_merged([jnp.vdot(g0, g0)], ax)
+        (asum,) = engine.psum_merged([pt.vdot(g0, g0)], ax)
         alpha1 = jnp.sqrt(asum)
         safe_a = jnp.where(alpha1 == 0.0, 1.0, alpha1)
         v0 = g0 / safe_a
@@ -620,10 +621,10 @@ def _sharded_lsmr_body(
 
             av = apply(v)
             u_m_new = av - alpha * u_m
-            bs = jnp.vdot(u_m_new, u_m_new)
+            bs = pt.vdot(u_m_new, u_m_new)
             if has_shift:
                 u_n_new = sqrt_damp * v - alpha * u_n
-                bs = bs + jnp.vdot(u_n_new, u_n_new)
+                bs = bs + pt.vdot(u_n_new, u_n_new)
             (beta_sq_,) = engine.psum_merged([bs], ax)
             beta_new = jnp.sqrt(beta_sq_)
             sb = jnp.where(beta_new == 0.0, 1.0, beta_new)
@@ -634,7 +635,7 @@ def _sharded_lsmr_body(
             atu = rapply(u_m_new)
             g_new = atu + sqrt_damp * u_n_new if has_shift else atu
             w_vec = g_new - beta_new * v
-            (as_,) = engine.psum_merged([jnp.vdot(w_vec, w_vec)], ax)
+            (as_,) = engine.psum_merged([pt.vdot(w_vec, w_vec)], ax)
             alpha_new = jnp.sqrt(as_)
             sa = jnp.where(alpha_new == 0.0, 1.0, alpha_new)
             v_new = w_vec / sa
@@ -786,9 +787,9 @@ def _build(mesh: Mesh, method: str, kind: str, aux, leaf_specs, statics):
         if st["ell"] > 0:
             out_specs["theta"] = P()
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 

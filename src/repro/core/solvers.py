@@ -527,7 +527,7 @@ def defcg(
                 x, r, rs_new, awr = kops.fused_cg_update(
                     x, r, p, ap, alpha, aw_flat
                 )
-                mu = waw_inv @ awr.astype(waw_inv.dtype)
+                mu = pt.matmul(waw_inv, awr.astype(waw_inv.dtype))
             else:
                 x, r, rs_new, _ = kops.fused_cg_update(x, r, p, ap, alpha)
             rr = rs_new
@@ -542,7 +542,7 @@ def defcg(
                 r, zvec, aw_flat if deflating else None
             )
             if deflating:
-                mu = waw_inv @ awz.astype(waw_inv.dtype)
+                mu = pt.matmul(waw_inv, awz.astype(waw_inv.dtype))
         beta = rs_new / jnp.where(rs == 0.0, 1.0, rs)
 
         p_new, _, _ = kops.fused_deflate_direction(zvec, p, beta, w_flat, mu)

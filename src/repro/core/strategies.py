@@ -73,6 +73,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import pytree as pt
 from repro.core.solvers import DRIFT_NOISE_FLOOR_EPS, RecycleData
 from repro.kernels import ops as kops
 
@@ -264,7 +265,7 @@ def harmonic_ritz_flat_core(
     rcond = jnp.maximum(jnp.asarray(jitter, G.dtype), 100.0 * eps) * m
     good = lam > rcond * lam[-1]
     s = jnp.where(good, 1.0 / jnp.sqrt(jnp.maximum(lam, 1e-300)), 0.0)
-    M = s[:, None] * (qg.T @ F @ qg) * s[None, :]
+    M = s[:, None] * pt.matmul(pt.matmul(qg.T, F), qg) * s[None, :]
     M = 0.5 * (M + M.T)
     zeta, Wm = jnp.linalg.eigh(M)
 
@@ -272,7 +273,7 @@ def harmonic_ritz_flat_core(
 
     # u folds the reduction and BOTH equilibrations, so it applies to the
     # raw (unnormalized) bases: u = D_z · D · Qg S w.
-    u = qg @ (s[:, None] * w_sel)
+    u = pt.matmul(qg, s[:, None] * w_sel)
     u = u * (d * dz)[:, None]
     u = u.astype(Z.dtype)
 

@@ -11,8 +11,9 @@ whose eigenvalues lie in [1, n·max(K)/4].  The solver is pluggable —
 with a :class:`repro.core.RecycleManager` carrying the deflation basis
 across Newton iterations (the paper's contribution).  Since the operator
 changes every Newton step (H½ moves with f), the manager recomputes
-``A⁽ⁱ⁾W`` each iteration — via ``KernelSystemOperator.basis_matvec``
-this is ONE fused multi-RHS Gram pass (each K-tile formed once for all k
+``A⁽ⁱ⁾W`` each iteration — via ``basis_matvec`` of
+``RBFKernelSystemOperator`` (or of ``KernelSystemOperator`` over a dense
+K) this is ONE fused multi-RHS Gram pass (each K-tile formed once for all k
 recycled vectors), not k sequential matvecs; both the matrix-free kernel
 matvec and the dense ``K @ V`` path batch natively.
 
@@ -37,7 +38,9 @@ from repro.core import (
     kernel_nystrom_preconditioner,
     randomized_nystrom,
 )
+from repro.core import pytree as pt
 from repro.core.api import solve_jit
+from repro.core.operators import RBFKernelSystemOperator
 from repro.core.solvers import cg_jit
 from repro.gp.kernels import RBFKernel
 
@@ -55,6 +58,26 @@ def logistic_quantities(f: jnp.ndarray, y: jnp.ndarray):
     return logp, grad, hdiag
 
 
+def newton_system(f: jnp.ndarray, y: jnp.ndarray, k_mv: Callable):
+    """The Newton system at latent ``f`` (paper Eq. 9–10).
+
+    Returns ``(sqrt_h, b, bg)``: the operator is ``A = I + H½ K H½``, the
+    right-hand side ``b = H½ K bg`` with ``bg = H f + ∇ log p(y|f)``, and
+    ``k_mv`` applies ``K``.
+    """
+    _, grad, hdiag = logistic_quantities(f, y)
+    sqrt_h = jnp.sqrt(hdiag)
+    bg = hdiag * f + grad
+    return sqrt_h, sqrt_h * k_mv(bg), bg
+
+
+def newton_step(k_mv: Callable, sqrt_h, bg, sol):
+    """``(a, f)`` from the solution ``sol`` of :func:`newton_system`:
+    ``a = bg − H½ sol`` and the next latent ``f = K a``."""
+    a_vec = bg - sqrt_h * sol
+    return a_vec, k_mv(a_vec)
+
+
 @dataclasses.dataclass
 class NewtonTrace:
     """Per-Newton-iteration record (mirrors the columns of paper Table 1)."""
@@ -63,6 +86,9 @@ class NewtonTrace:
     psi: List[float] = dataclasses.field(default_factory=list)
     solver_iterations: List[int] = dataclasses.field(default_factory=list)
     solver_matvecs: List[int] = dataclasses.field(default_factory=list)
+    solver_converged: List[bool] = dataclasses.field(default_factory=list)
+    # Recovery-ladder rung each solve ended on (0 = clean; spec path only).
+    solver_rungs: List[int] = dataclasses.field(default_factory=list)
     cumulative_time: List[float] = dataclasses.field(default_factory=list)
     residual_traces: List = dataclasses.field(default_factory=list)
 
@@ -159,10 +185,7 @@ def laplace_gpc(
     converged = False
 
     for it in range(max_newton):
-        logp, grad, hdiag = logistic_quantities(f, y)
-        sqrt_h = jnp.sqrt(hdiag)
-        bg = hdiag * f + grad
-        b = sqrt_h * k_mv(bg)
+        sqrt_h, b, bg = newton_system(f, y, k_mv)
 
         t0 = time.perf_counter()
         if solver == "cholesky":
@@ -173,7 +196,14 @@ def laplace_gpc(
             xsol = cholesky_solve(amat, b)
             info = None
         else:
-            a_op = KernelSystemOperator(k_mv, sqrt_h)
+            if dense_matvec:
+                a_op = KernelSystemOperator(k_mv, sqrt_h)
+            else:
+                # x rides as a pytree leaf: a closure would bake the (n, d)
+                # data into every compiled solve as a constant.
+                a_op = RBFKernelSystemOperator(
+                    x, sqrt_h, kernel.theta, kernel.lengthscale, block, impl
+                )
             if solver == "spec":
                 M = None
                 if spec.precond == "jacobi":
@@ -183,7 +213,7 @@ def laplace_gpc(
                         if dense_matvec
                         else jnp.full(n, kernel.theta**2, x.dtype)
                     )
-                    M = jacobi(1.0 + hdiag * diag_k)
+                    M = jacobi(1.0 + sqrt_h * sqrt_h * diag_k)
                 elif spec.precond == "nystrom":
                     if k_sketch is None:
                         key = (
@@ -221,21 +251,23 @@ def laplace_gpc(
             else:
                 raise ValueError(f"unknown solver={solver!r}")
             xsol, info = res.x, res.info
+            rung = int(res.report.rung) if solver == "spec" else 0
         jax.block_until_ready(xsol)
         solve_time += time.perf_counter() - t0
 
-        a_vec = bg - sqrt_h * xsol
-        f = k_mv(a_vec)
+        a_vec, f = newton_step(k_mv, sqrt_h, bg, xsol)
         x_prev = xsol
 
         logp_new, _, _ = logistic_quantities(f, y)
-        psi = logp_new - 0.5 * jnp.dot(a_vec, f)
+        psi = logp_new - 0.5 * pt.vdot(a_vec, f)
 
         trace.logp.append(float(logp_new))
         trace.psi.append(float(psi))
         trace.cumulative_time.append(solve_time)
         if info is not None:
             trace.solver_iterations.append(int(info.iterations))
+            trace.solver_converged.append(bool(info.converged))
+            trace.solver_rungs.append(rung)
             # The one-off Nyström sketch cost is charged to the system
             # that built it — honest a-priori-subspace accounting.
             trace.solver_matvecs.append(int(info.matvecs) + sketch_matvecs)
@@ -246,6 +278,8 @@ def laplace_gpc(
                 )
         else:
             trace.solver_iterations.append(n)  # direct solve ≙ full rank
+            trace.solver_converged.append(True)
+            trace.solver_rungs.append(0)
             trace.solver_matvecs.append(0)
 
         if jnp.abs(psi - psi_prev) < newton_tol:

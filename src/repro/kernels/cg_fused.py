@@ -41,13 +41,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
+from repro.kernels.blocks import F32, block_spec, round_up
 
 _LANES = 128
-
-
-def _round_up(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m
 
 
 def _acc(dtype):
@@ -55,6 +51,10 @@ def _acc(dtype):
     if dtype == jnp.float64:
         return jnp.float64
     return jnp.promote_types(dtype, jnp.float32)
+
+
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=F32)
 
 
 def _pad_rows(v: jnp.ndarray, n_pad: int) -> jnp.ndarray:
@@ -142,15 +142,15 @@ def fused_cg_update_pallas(
     """
     n = x.shape[0]
     rows = max(8, block // _LANES)
-    n_pad = _round_up(n, _LANES * rows)
+    n_pad = round_up(n, _LANES * rows)
     nrows = n_pad // _LANES
     grid = (nrows // rows,)
 
     x2, r2, p2, ap2 = (_pad_rows(v, n_pad) for v in (x, r, p, ap))
     alpha2 = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
 
-    vec_spec = pl.BlockSpec((rows, _LANES), lambda i: (i, 0))
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    vec_spec = block_spec((rows, _LANES), lambda i: (i, 0))
+    smem = functools.partial(block_spec, memory_space=pltpu.SMEM)
     in_specs = [smem((1, 1), lambda i: (0, 0))] + [vec_spec] * 4
     out_specs = [
         vec_spec,
@@ -168,7 +168,7 @@ def fused_cg_update_pallas(
         k = aw.shape[0]
         args.append(_pad_rows(aw, n_pad))
         in_specs.append(
-            pl.BlockSpec((k, rows, _LANES), lambda i: (0, i, 0))
+            block_spec((k, rows, _LANES), lambda i: (0, i, 0))
         )
         out_specs.append(smem((k, 1), lambda i: (0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((k, 1), jnp.float32))
@@ -182,7 +182,7 @@ def fused_cg_update_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="fused_cg_update",
     )(*args)
@@ -204,7 +204,7 @@ def fused_cg_update_chunked(x, r, p, ap, alpha, aw=None):
     r_new = r - alpha * ap
     ra = r_new.astype(acc)
     rr = jnp.sum(ra * ra)
-    awr = aw.astype(acc) @ ra if aw is not None else None
+    awr = _mm(aw.astype(acc), ra) if aw is not None else None
     return x_new, r_new, rr, awr
 
 
@@ -260,13 +260,13 @@ def fused_rz_reduce_pallas(
     """``(rᵀz, AW @ z | None)`` in one read of ``r, z, AW`` (f32 accum)."""
     n = r.shape[0]
     rows = max(8, block // _LANES)
-    n_pad = _round_up(n, _LANES * rows)
+    n_pad = round_up(n, _LANES * rows)
     nrows = n_pad // _LANES
     grid = (nrows // rows,)
 
     r2, z2 = _pad_rows(r, n_pad), _pad_rows(z, n_pad)
-    vec_spec = pl.BlockSpec((rows, _LANES), lambda i: (i, 0))
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    vec_spec = block_spec((rows, _LANES), lambda i: (i, 0))
+    smem = functools.partial(block_spec, memory_space=pltpu.SMEM)
 
     in_specs = [vec_spec, vec_spec]
     out_specs = [smem((1, 1), lambda i: (0, 0))]
@@ -275,7 +275,7 @@ def fused_rz_reduce_pallas(
     if aw is not None:
         k = aw.shape[0]
         args.append(_pad_rows(aw, n_pad))
-        in_specs.append(pl.BlockSpec((k, rows, _LANES), lambda i: (0, i, 0)))
+        in_specs.append(block_spec((k, rows, _LANES), lambda i: (0, i, 0)))
         out_specs.append(smem((k, 1), lambda i: (0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((k, 1), jnp.float32))
         kernel = functools.partial(_rz_reduce_aw_kernel, k=k)
@@ -288,7 +288,7 @@ def fused_rz_reduce_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="fused_rz_reduce",
     )(*args)
@@ -302,7 +302,7 @@ def fused_rz_reduce_chunked(r, z, aw=None):
     acc = _acc(r.dtype)
     za = z.astype(acc)
     rz = jnp.sum(r.astype(acc) * za)
-    awz = aw.astype(acc) @ za if aw is not None else None
+    awz = _mm(aw.astype(acc), za) if aw is not None else None
     return rz, awz
 
 
@@ -353,7 +353,7 @@ def lsmr_update_pallas(
     """
     n = x.shape[0]
     rows = max(8, block // _LANES)
-    n_pad = _round_up(n, _LANES * rows)
+    n_pad = round_up(n, _LANES * rows)
     nrows = n_pad // _LANES
     grid = (nrows // rows,)
 
@@ -364,8 +364,8 @@ def lsmr_update_pallas(
         jnp.asarray(c2, jnp.float32),
     ]).reshape(3, 1)
 
-    vec_spec = pl.BlockSpec((rows, _LANES), lambda i: (i, 0))
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    vec_spec = block_spec((rows, _LANES), lambda i: (i, 0))
+    smem = functools.partial(block_spec, memory_space=pltpu.SMEM)
     outs = pl.pallas_call(
         _lsmr_update_kernel,
         grid=grid,
@@ -376,7 +376,7 @@ def lsmr_update_pallas(
             jax.ShapeDtypeStruct((nrows, _LANES), hbar.dtype),
             jax.ShapeDtypeStruct((nrows, _LANES), h.dtype),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="lsmr_update",
     )(c2_, x2, hb2, h2, v2)
@@ -448,7 +448,7 @@ def fused_deflate_direction_pallas(
     n = r.shape[0]
     k = w.shape[0]
     rows = max(8, block // _LANES)
-    n_pad = _round_up(n, _LANES * rows)
+    n_pad = round_up(n, _LANES * rows)
     nrows = n_pad // _LANES
     grid = (nrows // rows,)
 
@@ -458,7 +458,7 @@ def fused_deflate_direction_pallas(
     mu2 = jnp.asarray(mu, jnp.float32).reshape(k, 1)
 
     have_buf = p_buf is not None
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    smem = functools.partial(block_spec, memory_space=pltpu.SMEM)
 
     if not have_buf:
         out = pl.pallas_call(
@@ -467,13 +467,13 @@ def fused_deflate_direction_pallas(
             in_specs=[
                 smem((1, 1), lambda i: (0, 0)),
                 smem((k, 1), lambda i: (0, 0)),
-                pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
-                pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
-                pl.BlockSpec((k, rows, _LANES), lambda i: (0, i, 0)),
+                block_spec((rows, _LANES), lambda i: (i, 0)),
+                block_spec((rows, _LANES), lambda i: (i, 0)),
+                block_spec((k, rows, _LANES), lambda i: (0, i, 0)),
             ],
-            out_specs=pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
+            out_specs=block_spec((rows, _LANES), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((nrows, _LANES), p.dtype),
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)
             ),
             interpret=interpret,
@@ -486,8 +486,8 @@ def fused_deflate_direction_pallas(
     pb2, ab2 = _pad_rows(p_buf, n_pad), _pad_rows(ap_buf, n_pad)
     idx2 = jnp.asarray(idx, jnp.int32).reshape(1)
 
-    vec = lambda: pl.BlockSpec((rows, _LANES), lambda i, idx_ref: (i, 0))
-    row = lambda: pl.BlockSpec(
+    vec = lambda: block_spec((rows, _LANES), lambda i, idx_ref: (i, 0))
+    row = lambda: block_spec(
         (1, rows, _LANES), lambda i, idx_ref: (idx_ref[0], i, 0)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -499,7 +499,7 @@ def fused_deflate_direction_pallas(
             vec(),  # r
             vec(),  # p
             vec(),  # ap
-            pl.BlockSpec(
+            block_spec(
                 (k, rows, _LANES), lambda i, idx_ref: (0, i, 0)
             ),  # w
             row(),  # p_buf (pass-through for aliasing)
@@ -517,7 +517,7 @@ def fused_deflate_direction_pallas(
             jax.ShapeDtypeStruct((m, nrows, _LANES), ap_buf.dtype),
         ],
         input_output_aliases={7: 1, 8: 2},
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="fused_deflate_direction",
     )(idx2, beta2, mu2, r2, p2, ap2, w2, pb2, ab2)
@@ -546,7 +546,8 @@ def _self_gram_kernel(s_ref, o_ref, acc_ref):
 
     sb = s_ref[...].astype(jnp.float32)  # (m_pad, bn)
     acc_ref[...] += jax.lax.dot_general(
-        sb, sb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        sb, sb, (((1,), (1,)), ((), ())),
+        precision=F32, preferred_element_type=jnp.float32,
     )
 
     @pl.when(j == pl.num_programs(0) - 1)
@@ -566,19 +567,19 @@ def self_gram_pallas(
     padded output rows are sliced off).
     """
     m, n = s.shape
-    m_pad = _round_up(max(m, 8), 8)
-    bn = min(_round_up(block, _LANES), _round_up(n, _LANES))
-    n_pad = _round_up(n, bn)
+    m_pad = round_up(max(m, 8), 8)
+    bn = min(round_up(block, _LANES), round_up(n, _LANES))
+    n_pad = round_up(n, bn)
     s_p = jnp.pad(s, ((0, m_pad - m), (0, n_pad - n)))
 
     out = pl.pallas_call(
         _self_gram_kernel,
         grid=(n_pad // bn,),
-        in_specs=[pl.BlockSpec((m_pad, bn), lambda j: (0, j))],
-        out_specs=pl.BlockSpec((m_pad, m_pad), lambda j: (0, 0)),
+        in_specs=[block_spec((m_pad, bn), lambda j: (0, j))],
+        out_specs=block_spec((m_pad, m_pad), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, m_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((m_pad, m_pad), jnp.float32)],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="self_gram",
     )(s_p)
@@ -595,13 +596,13 @@ def self_gram_chunked(s: jnp.ndarray, block: int = 8192) -> jnp.ndarray:
     m, n = s.shape
     if n <= block:
         sa = s.astype(acc)
-        return sa @ sa.T
-    n_pad = _round_up(n, block)
+        return _mm(sa, sa.T)
+    n_pad = round_up(n, block)
     sp = jnp.pad(s, ((0, 0), (0, n_pad - n))).astype(acc)
     blocks = sp.reshape(m, n_pad // block, block).transpose(1, 0, 2)
 
     def body(g, sb):
-        return g + sb @ sb.T, None
+        return g + _mm(sb, sb.T), None
 
     g0 = jnp.zeros((m, m), acc)
     g, _ = jax.lax.scan(body, g0, blocks)
@@ -625,11 +626,11 @@ def _recombine_blocks_kernel(ut_ref, s_ref, o_ref, *, m_pad, k_pad):
     sb = s_ref[...].astype(jnp.float32)  # (2·m_pad, bn)
     top = jax.lax.dot_general(
         ut, sb[:m_pad], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=F32, preferred_element_type=jnp.float32,
     )
     bot = jax.lax.dot_general(
         ut, sb[m_pad:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=F32, preferred_element_type=jnp.float32,
     )
     o_ref[:k_pad] = top.astype(o_ref.dtype)
     o_ref[k_pad:] = bot.astype(o_ref.dtype)
@@ -654,10 +655,10 @@ def recombine_blocks_pallas(
     m = m2 // 2
     assert 2 * m == m2, "recombine_blocks needs an even (2m, n) stack"
     k = u.shape[1]
-    m_pad = _round_up(max(m, 8), 8)
-    k_pad = _round_up(max(k, 8), 8)
-    bn = min(_round_up(block, _LANES), _round_up(n, _LANES))
-    n_pad = _round_up(n, bn)
+    m_pad = round_up(max(m, 8), 8)
+    k_pad = round_up(max(k, 8), 8)
+    bn = min(round_up(block, _LANES), round_up(n, _LANES))
+    n_pad = round_up(n, bn)
 
     s_p = jnp.concatenate(
         [
@@ -674,12 +675,12 @@ def recombine_blocks_pallas(
         functools.partial(_recombine_blocks_kernel, m_pad=m_pad, k_pad=k_pad),
         grid=(n_pad // bn,),
         in_specs=[
-            pl.BlockSpec((k_pad, m_pad), lambda j: (0, 0)),
-            pl.BlockSpec((2 * m_pad, bn), lambda j: (0, j)),
+            block_spec((k_pad, m_pad), lambda j: (0, 0)),
+            block_spec((2 * m_pad, bn), lambda j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((2 * k_pad, bn), lambda j: (0, j)),
+        out_specs=block_spec((2 * k_pad, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((2 * k_pad, n_pad), s.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="recombine_blocks",
     )(ut_p, s_p)
@@ -699,15 +700,16 @@ def recombine_blocks_chunked(
     ut = u.astype(acc).T  # (k, m)
     if n <= block:
         sa = s.astype(acc)
-        return jnp.concatenate([ut @ sa[:m], ut @ sa[m:]], axis=0).astype(
-            s.dtype
-        )
-    n_pad = _round_up(n, block)
+        out = jnp.concatenate([_mm(ut, sa[:m]), _mm(ut, sa[m:])], axis=0)
+        return out.astype(s.dtype)
+    n_pad = round_up(n, block)
     sp = jnp.pad(s, ((0, 0), (0, n_pad - n))).astype(acc)
     blocks = sp.reshape(m2, n_pad // block, block).transpose(1, 0, 2)
 
     def body(_, sb):
-        return None, jnp.concatenate([ut @ sb[:m], ut @ sb[m:]], axis=0)
+        return None, jnp.concatenate(
+            [_mm(ut, sb[:m]), _mm(ut, sb[m:])], axis=0
+        )
 
     _, outs = jax.lax.scan(body, None, blocks)
     k = u.shape[1]
@@ -725,7 +727,7 @@ def fused_deflate_direction_chunked(
     p_new = beta * p + r
     if w is not None:
         p_new = p_new - (
-            mu.astype(_acc(w.dtype)) @ w.astype(_acc(w.dtype))
+            _mm(mu.astype(_acc(w.dtype)), w.astype(_acc(w.dtype)))
         ).astype(p.dtype)
     if p_buf is None:
         return p_new, None, None

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
+from repro.kernels.blocks import block_spec, round_up
 
 _NEG_INF = -1e30
 
@@ -130,11 +130,11 @@ def flash_attention_pallas(
     group = h // hkv
     scale = dh**-0.5 if scale is None else scale
 
-    bq = min(block_q, _round_up(sq, 8))
-    bk = min(block_k, _round_up(sk, 8))
-    sq_p = _round_up(sq, bq)
-    sk_p = _round_up(sk, bk)
-    dh_p = _round_up(dh, 128)
+    bq = min(block_q, round_up(sq, 8))
+    bk = min(block_k, round_up(sk, 8))
+    sq_p = round_up(sq, bq)
+    sk_p = round_up(sk, bk)
+    dh_p = round_up(dh, 128)
 
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, dh_p - dh)))
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, sk_p - sk), (0, dh_p - dh)))
@@ -159,22 +159,22 @@ def flash_attention_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, dh_p), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec(
+            block_spec((1, bq, dh_p), lambda bh, i, j: (bh, i, 0)),
+            block_spec(
                 (1, bk, dh_p), lambda bh, i, j, g=group: (bh // g, j, 0)
             ),
-            pl.BlockSpec(
+            block_spec(
                 (1, bk, dh_p), lambda bh, i, j, g=group: (bh // g, j, 0)
             ),
         ],
-        out_specs=pl.BlockSpec((1, bq, dh_p), lambda bh, i, j: (bh, i, 0)),
+        out_specs=block_spec((1, bq, dh_p), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, dh_p), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, dh_p), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -182,6 +182,3 @@ def flash_attention_pallas(
     )(qp, kp, vp)
     return out.reshape(b, h, sq_p, dh_p)[:, :, :sq, :dh]
 
-
-def _round_up(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m

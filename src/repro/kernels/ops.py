@@ -13,6 +13,10 @@ Every op takes ``impl ∈ {"auto", "pallas", "interpret", "reference",
   use;
 * ``auto``       — ``pallas`` on TPU, ``chunked`` elsewhere.
 
+Mosaic has no 64-bit types, so ``pallas`` (chosen or resolved from
+``auto``) raises a ``ValueError`` for float64 operands instead of routing
+them anywhere else: the TPU path runs in float32.
+
 Keeping the kernel and the scan implementation in one file per op — with a
 single oracle — is the repo's kernel contract (see kernels/EXAMPLE.md).
 """
@@ -40,6 +44,7 @@ from repro.kernels.cg_fused import (
     self_gram_chunked,
     self_gram_pallas,
 )
+from repro.kernels.blocks import F32
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rbf_matvec import rbf_matvec_pallas, rbf_matvec_rect_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
@@ -47,9 +52,22 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 _NEG_INF = -1e30
 
 
-def _resolve(impl: str) -> str:
+def _resolve(impl: str, op: str, *operands) -> str:
+    """Resolve ``auto`` and refuse 64-bit float operands on ``pallas``."""
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "chunked"
+        impl = "pallas" if jax.default_backend() == "tpu" else "chunked"
+    if impl == "pallas":
+        for a in operands:
+            if (
+                a is not None
+                and jnp.issubdtype(a.dtype, jnp.floating)
+                and jnp.dtype(a.dtype).itemsize == 8
+            ):
+                raise ValueError(
+                    f"{op}: impl='pallas' got a {jnp.dtype(a.dtype).name} "
+                    "operand; the TPU kernels take no 64-bit types — pass "
+                    "float32 data, or impl='chunked'/'reference'"
+                )
     return impl
 
 
@@ -74,7 +92,7 @@ def rbf_matvec(
     """
     squeeze = v.ndim == 1
     v2 = v[:, None] if squeeze else v
-    impl = _resolve(impl)
+    impl = _resolve(impl, "rbf_matvec", x, v)
     if impl in ("pallas", "interpret"):
         out = rbf_matvec_pallas(
             x / lengthscale,
@@ -106,9 +124,9 @@ def _rbf_matvec_chunked(xs: jnp.ndarray, vs: jnp.ndarray, block: int):
 
     def body(_, xi):
         sq_i = jnp.sum(xi * xi, axis=1, keepdims=True)
-        cross = xi @ xs.T
+        cross = jnp.matmul(xi, xs.T, precision=F32)
         d2 = jnp.maximum(sq_i + sq_all[None, :] - 2.0 * cross, 0.0)
-        return None, jnp.exp(-0.5 * d2) @ vs
+        return None, jnp.matmul(jnp.exp(-0.5 * d2), vs, precision=F32)
 
     _, ys = jax.lax.scan(body, None, xp.reshape(-1, nb, d))
     return ys.reshape(n_pad, vs.shape[1])[:n]
@@ -135,7 +153,7 @@ def rbf_matvec_rect(
     """
     squeeze = v.ndim == 1
     v2 = v[:, None] if squeeze else v
-    impl = _resolve(impl)
+    impl = _resolve(impl, "rbf_matvec_rect", x_rows, x_cols, v)
     if impl in ("pallas", "interpret"):
         out = rbf_matvec_rect_pallas(
             x_rows / lengthscale,
@@ -169,9 +187,9 @@ def _rbf_matvec_rect_chunked(
 
     def body(_, xi):
         sq_i = jnp.sum(xi * xi, axis=1, keepdims=True)
-        cross = xi @ xc.T
+        cross = jnp.matmul(xi, xc.T, precision=F32)
         d2 = jnp.maximum(sq_i + sq_cols[None, :] - 2.0 * cross, 0.0)
-        return None, jnp.exp(-0.5 * d2) @ vs
+        return None, jnp.matmul(jnp.exp(-0.5 * d2), vs, precision=F32)
 
     _, ys = jax.lax.scan(body, None, xp.reshape(-1, nb, d))
     return ys.reshape(m_pad, vs.shape[1])[:m]
@@ -199,7 +217,7 @@ def fused_cg_update(
     ``rᵀr`` recurrence scalar and the deflation GEMV ``(AW)ᵀ r`` (``aw``
     is the flat ``(k, n)`` basis; pass ``None`` when not deflating).
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "fused_cg_update", x, r, p, ap, aw)
     if impl in ("pallas", "interpret"):
         return fused_cg_update_pallas(
             x, r, p, ap, alpha, aw,
@@ -227,7 +245,7 @@ def fused_rz_reduce(
     residual update, so it cannot ride in :func:`fused_cg_update`) plus
     the deflation GEMV taken in the preconditioned inner product.
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "fused_rz_reduce", r, z, aw)
     if impl in ("pallas", "interpret"):
         return fused_rz_reduce_pallas(
             r, z, aw, block=block, interpret=(impl == "interpret")
@@ -263,7 +281,9 @@ def fused_deflate_direction(
     update (``w is None``) is two-operand elementwise work that XLA
     already fuses optimally, so it lowers to the chunked form everywhere.
     """
-    impl = _resolve(impl)
+    impl = _resolve(
+        impl, "fused_deflate_direction", r, p, w, ap, p_buf, ap_buf
+    )
     if impl in ("pallas", "interpret") and w is not None:
         return fused_deflate_direction_pallas(
             r, p, beta, w, mu, ap, idx, p_buf, ap_buf,
@@ -300,7 +320,7 @@ def lsmr_update(
     :func:`fused_cg_update`.  The rotation scalars ``c0, c1, c2`` are the
     pre-reduced Givens quantities (O(1) host-free scalars).
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "lsmr_update", x, hbar, h, v)
     if impl in ("pallas", "interpret"):
         return lsmr_update_pallas(
             x, hbar, h, v, c0, c1, c2,
@@ -326,7 +346,7 @@ def self_gram(
     GEMM (one pass over the basis data).  Accumulates in f32 on the TPU
     kernel and in the acc dtype (f64-preserving) elsewhere.
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "self_gram", s)
     if impl in ("pallas", "interpret"):
         return self_gram_pallas(
             s, block=min(block, 2048), interpret=(impl == "interpret")
@@ -354,7 +374,7 @@ def recombine_blocks(
     already-stored quantities in ONE pass over the basis data — the
     paper's zero-extra-matvec refresh (``core/strategies.py``).
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "recombine_blocks", s)
     if impl in ("pallas", "interpret"):
         return recombine_blocks_pallas(
             s, u, block=min(block, 2048), interpret=(impl == "interpret")
@@ -384,7 +404,7 @@ def attention(
     block_k: int = 1024,
 ) -> jnp.ndarray:
     """GQA softmax attention; see ref.mha_attention for semantics."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "attention", q, k, v)
     if impl in ("pallas", "interpret"):
         return flash_attention_pallas(
             q, k, v,
@@ -507,7 +527,7 @@ def ssd(
 ):
     """SSD scan; optionally seeded with / returning the (b,h,p,n) state —
     the prefill path (chunked scan + final state handoff to decode)."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "ssd", x, bmat, cmat)
     if impl in ("pallas", "interpret") and not return_state and initial_state is None:
         return ssd_scan_pallas(
             x, dt, a, bmat, cmat, d, chunk=chunk,

@@ -35,7 +35,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
+from repro.kernels.blocks import F32, block_spec, round_up
+
+# Both contractions run at full f32 precision (F32).  On a v5e at n = 2^15,
+# d = 784 (float64 reference on 256 rows) the kernel's relative error is
+# 3.7e-6 this way, 4.7e-3 with both at the default one-bf16-pass
+# precision and 5.4e-3 with only the distance term at HIGHEST: neither
+# cheaper choice is within a 1e-5 solve tolerance.  HIGHEST on the K·v
+# term is what limits the block to 256 (512 overflows VMEM).
 
 
 def _rbf_matvec_kernel(x_i_ref, x_j_ref, v_ref, o_ref, acc_ref):
@@ -57,13 +64,15 @@ def _rbf_matvec_kernel(x_i_ref, x_j_ref, v_ref, o_ref, acc_ref):
         xi,
         xj,
         (((1,), (1,)), ((), ())),
+        precision=F32,
         preferred_element_type=jnp.float32,
     )  # (bm, bn)
     dist2 = jnp.maximum(sq_i + sq_j - 2.0 * cross, 0.0)
     kb = jnp.exp(-0.5 * dist2)
 
     acc_ref[...] += jax.lax.dot_general(
-        kb, vj, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        kb, vj, (((1,), (0,)), ((), ())),
+        precision=F32, preferred_element_type=jnp.float32,
     )
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -96,13 +105,13 @@ def rbf_matvec_pallas(
     n, d = x_scaled.shape
     _, r = v_scaled.shape
 
-    bm = min(block_m, max(_round_up(n, 8), 8))
-    bn = min(block_n, max(_round_up(n, 8), 8))
-    n_m = _round_up(n, bm)
-    n_n = _round_up(n, bn)
+    bm = min(block_m, max(round_up(n, 8), 8))
+    bn = min(block_n, max(round_up(n, 8), 8))
+    n_m = round_up(n, bm)
+    n_n = round_up(n, bn)
     n_pad = max(n_m, n_n)
-    d_pad = _round_up(d, 128)
-    r_pad = _round_up(r, 8)
+    d_pad = round_up(d, 128)
+    r_pad = round_up(r, 8)
 
     x_p = jnp.pad(x_scaled, ((0, n_pad - n), (0, d_pad - d)))
     v_p = jnp.pad(v_scaled, ((0, n_pad - n), (0, r_pad - r)))
@@ -112,14 +121,14 @@ def rbf_matvec_pallas(
         _rbf_matvec_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, d_pad), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, d_pad), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, r_pad), lambda i, j: (j, 0)),
+            block_spec((bm, d_pad), lambda i, j: (i, 0)),
+            block_spec((bn, d_pad), lambda i, j: (j, 0)),
+            block_spec((bn, r_pad), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, r_pad), lambda i, j: (i, 0)),
+        out_specs=block_spec((bm, r_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, r_pad), v_scaled.dtype),
         scratch_shapes=[pltpu.VMEM((bm, r_pad), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -155,12 +164,12 @@ def rbf_matvec_rect_pallas(
     n, _ = x_cols.shape
     _, r = v_scaled.shape
 
-    bm = min(block_m, max(_round_up(m, 8), 8))
-    bn = min(block_n, max(_round_up(n, 8), 8))
-    m_pad = _round_up(m, bm)
-    n_pad = _round_up(n, bn)
-    d_pad = _round_up(d, 128)
-    r_pad = _round_up(r, 8)
+    bm = min(block_m, max(round_up(m, 8), 8))
+    bn = min(block_n, max(round_up(n, 8), 8))
+    m_pad = round_up(m, bm)
+    n_pad = round_up(n, bn)
+    d_pad = round_up(d, 128)
+    r_pad = round_up(r, 8)
 
     xr_p = jnp.pad(x_rows, ((0, m_pad - m), (0, d_pad - d)))
     xc_p = jnp.pad(x_cols, ((0, n_pad - n), (0, d_pad - d)))
@@ -171,14 +180,14 @@ def rbf_matvec_rect_pallas(
         _rbf_matvec_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, d_pad), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, d_pad), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, r_pad), lambda i, j: (j, 0)),
+            block_spec((bm, d_pad), lambda i, j: (i, 0)),
+            block_spec((bn, d_pad), lambda i, j: (j, 0)),
+            block_spec((bn, r_pad), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, r_pad), lambda i, j: (i, 0)),
+        out_specs=block_spec((bm, r_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, r_pad), v_scaled.dtype),
         scratch_shapes=[pltpu.VMEM((bm, r_pad), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -186,6 +195,3 @@ def rbf_matvec_rect_pallas(
     )(xr_p, xc_p, v_p)
     return out[:m, :r]
 
-
-def _round_up(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
+from repro.kernels.blocks import block_spec, round_up
 
 
 def _ssd_kernel(x_ref, dt_ref, adt_ref, b_ref, c_ref, y_ref, h_ref):
@@ -104,8 +104,8 @@ def ssd_scan_pallas(
     g, n = bmat.shape[2], bmat.shape[3]
     hpg = h // g
 
-    c = min(chunk, _round_up(l, 8))
-    l_p = _round_up(l, c)
+    c = min(chunk, round_up(l, 8))
+    l_p = round_up(l, c)
 
     # Head-major layouts; fold a into a·dt; expand B/C across head groups.
     xh = jnp.pad(x.transpose(0, 2, 1, 3), ((0, 0), (0, 0), (0, l_p - l), (0, 0)))
@@ -121,16 +121,16 @@ def ssd_scan_pallas(
         _ssd_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, c, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, c, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, c, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, c, n), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, c, n), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            block_spec((1, 1, c, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            block_spec((1, 1, c, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            block_spec((1, 1, c, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            block_spec((1, 1, c, n), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            block_spec((1, 1, c, n), lambda bi, hi, ci: (bi, hi, ci, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, c, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+        out_specs=block_spec((1, 1, c, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, l_p, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -142,6 +142,3 @@ def ssd_scan_pallas(
         y = y + x * d[None, None, :, None]
     return y
 
-
-def _round_up(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m

@@ -4,7 +4,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 The two lines above MUST stay first: jax locks the device count at first
-init, and the production meshes need 512 placeholder host devices.
+init, and the production meshes need 512 placeholder host devices.  The
+run is pinned to the CPU platform in-process (``jax_platforms`` below), so
+on a TPU host it never takes the chip from another process.
 
 For each cell we build abstract params/optimizer/batch/cache trees
 (``jax.eval_shape`` — nothing is allocated), attach the production
@@ -30,7 +32,10 @@ import time
 import traceback
 
 import jax
-import jax.numpy as jnp
+
+jax.config.update("jax_platforms", "cpu")
+
+import jax.numpy as jnp  # noqa: E402
 
 from repro import models
 from repro.configs.registry import ARCH_IDS, SHAPES, get_config, shape_applicable
@@ -40,13 +45,6 @@ from repro.launch import steps as steps_lib
 from repro.models import sharding as shd_env
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "../../../artifacts/dryrun")
-
-
-def _use_mesh(mesh):
-    try:
-        return jax.sharding.use_mesh(mesh)
-    except AttributeError:  # older jax
-        return mesh
 
 
 def _memory_dict(mem) -> dict:
@@ -177,7 +175,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, outdir: str,
         moment_dtype = jnp.bfloat16 if cfg.total_params() > 1e11 else jnp.float32
         record["moment_dtype"] = str(jnp.dtype(moment_dtype))
 
-        with _use_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape.kind == "train":
                 opt_s = jax.eval_shape(
                     lambda p: steps_lib.init_opt_state(p, moment_dtype),

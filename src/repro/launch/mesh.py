@@ -27,9 +27,22 @@ from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import sharding as shd
+
+
+def auto_mesh(shape, axes, devices) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The solver and the model code shard by ``NamedSharding`` /
+    ``shard_map`` specs and let the compiler propagate the rest, which
+    needs Auto axes; ``jax.make_mesh`` now defaults to Explicit ones.
+    """
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(AxisType.Auto,) * len(axes), devices=devices,
+    )
 
 
 def make_solve_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -51,7 +64,7 @@ def make_solve_mesh(n_devices: Optional[int] = None) -> Mesh:
             f"n_devices={n_devices} out of range: this process has "
             f"{len(devices)} devices"
         )
-    return jax.make_mesh((n,), ("solve",), devices=devices[:n])
+    return auto_mesh((n,), ("solve",), devices[:n])
 
 
 def solve_state_shardings(mesh: Mesh) -> Any:
@@ -90,7 +103,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "run under dryrun.py (it sets xla_force_host_platform_device_count)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return auto_mesh(shape, axes, devices[:n])
 
 
 def axis_env_for(mesh: Mesh, *, batch_shardable: bool = True) -> Dict[str, Any]:

@@ -37,7 +37,7 @@ def make_mesh_auto():
         return mesh_lib.make_production_mesh(multi_pod=True)
     if n >= 256:
         return mesh_lib.make_production_mesh(multi_pod=False)
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return mesh_lib.auto_mesh((n, 1), ("data", "model"), jax.devices())
 
 
 def build(arch: str, preset: str, batch: int, seq: int, lr: float):
