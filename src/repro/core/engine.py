@@ -260,7 +260,8 @@ def run_recording_loop(
 
         def scan_body(state, _):
             active = active_fn(state)
-            state, emit = step(state, active, True)
+            with jax.named_scope("engine.iteration"):
+                state, emit = step(state, active, True)
             emit = jax.tree_util.tree_map(
                 lambda e: jnp.where(active, e, jnp.zeros_like(e)), emit
             )
@@ -272,7 +273,8 @@ def run_recording_loop(
         return active_fn(state)
 
     def body(state):
-        return step(state, jnp.bool_(True), False)[0]
+        with jax.named_scope("engine.iteration"):
+            return step(state, jnp.bool_(True), False)[0]
 
     state = jax.lax.while_loop(cond, body, state)
     return state, rows

@@ -319,6 +319,7 @@ class RBFKernelSystemOperator:
     block: int = 1024
     impl: str = "auto"
 
+    @jax.named_scope("operator.gram_matvec")
     def kernel_matvec(self, u: jnp.ndarray) -> jnp.ndarray:
         """``K(X, X) @ u`` — (n,) or column-stacked (n, r)."""
         from repro.kernels import ops as kops

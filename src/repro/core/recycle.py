@@ -131,6 +131,7 @@ class RecycleState:
         return cls(*children)
 
 
+@jax.named_scope("recycle.extract")
 def harmonic_ritz(
     Z: Pytree,
     AZ: Pytree,
@@ -275,6 +276,7 @@ def _extract_next_basis(
     return W, AW, theta
 
 
+@jax.named_scope("recycle.refresh_aw")
 def _apply_basis_flat(A, unravel, w_flat: jnp.ndarray) -> jnp.ndarray:
     """``A @ W`` for a flat ``(k, n)`` basis — one multi-RHS application
     through the operator's pytree coordinates."""

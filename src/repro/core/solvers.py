@@ -373,6 +373,7 @@ def defcg(
         k = pt.basis_size(W)
         w_flat = pt.ravel_basis(W)
 
+        @jax.named_scope("recycle.refresh_aw")
         def _apply_basis(w_f):
             # One fused multi-RHS operator application (each K-tile /
             # linearization formed once for all k vectors), not k

@@ -159,6 +159,7 @@ def _select_positive_ritz(zeta, Wm, k: int, select: str):
     return w_sel, theta, slot_ok
 
 
+@jax.named_scope("recycle.extract")
 def harmonic_ritz_flat_core(
     Z: jnp.ndarray,
     AZ: jnp.ndarray,

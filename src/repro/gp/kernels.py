@@ -43,7 +43,3 @@ class RBFKernel:
             )
 
         return mv
-
-    def matvec_cost_flops(self, n: int, d: int) -> float:
-        """Flops of one fused Gram matvec (distance matmul dominates)."""
-        return 2.0 * n * n * d + 6.0 * n * n

@@ -23,7 +23,7 @@ The logistic likelihood p(y_i|f_i) = σ(y_i f_i) with y ∈ {−1, +1}.
 from __future__ import annotations
 
 import dataclasses
-import time
+import itertools
 from typing import Callable, List, Optional
 
 import jax
@@ -43,6 +43,11 @@ from repro.core.api import solve_jit
 from repro.core.operators import RBFKernelSystemOperator
 from repro.core.solvers import cg_jit
 from repro.gp.kernels import RBFKernel
+from repro.runtime import spans
+
+# Every blocking read of a fit waits in a span of this name.
+WAIT = "laplace.wait"
+_fit_ids = itertools.count(1)
 
 
 def log_sigmoid(z):
@@ -154,7 +159,17 @@ def laplace_gpc(
 
     The returned trace contains per-iteration log p(y|f), Ψ, solver
     iteration/matvec counts and cumulative wall time spent in the linear
-    solver — everything paper Table 1 / Figs 2–3 report.
+    solver (the ``laplace.solve`` spans) — everything paper Table 1 /
+    Figs 2–3 report.
+
+    Spans (:mod:`repro.runtime.spans`): one ``laplace.fit`` (attrs
+    ``fit``, a process-wide id, ``systems`` and ``syncs``) holds one
+    ``laplace.system`` per Newton system, which holds
+    ``laplace.newton_system``, ``laplace.solve`` and
+    ``laplace.newton_step``.  Every device read is one ``laplace.wait``
+    span: on the spec path 8 per system (the rung, the solution, log p,
+    Ψ, iterations, converged, matvecs, the ΔΨ test) and 2 per fit (the
+    returned Ψ and log p).
     """
     n = x.shape[0]
     f = jnp.zeros(n, x.dtype)
@@ -178,119 +193,133 @@ def laplace_gpc(
     k_sketch = None  # once-per-call Nyström sketch (U, lam) of K
     sketch_matvecs = 0
 
+    def solve(sqrt_h, b, x_prev):
+        """One Newton system's solve: ``(x, info, rung)``."""
+        nonlocal solve_state, k_sketch, sketch_matvecs
+        if solver == "cholesky":
+            amat = (
+                jnp.eye(n, dtype=x.dtype)
+                + sqrt_h[:, None] * k_dense * sqrt_h[None, :]
+            )
+            return cholesky_solve(amat, b), None, 0
+        if dense_matvec:
+            a_op = KernelSystemOperator(k_mv, sqrt_h)
+        else:
+            # x rides as a pytree leaf: a closure would bake the (n, d)
+            # data into every compiled solve as a constant.
+            a_op = RBFKernelSystemOperator(
+                x, sqrt_h, kernel.theta, kernel.lengthscale, block, impl
+            )
+        if solver == "spec":
+            M = None
+            if spec.precond == "jacobi":
+                # diag(A) = 1 + h_i k(x_i, x_i) — exact, host-free.
+                diag_k = (
+                    jnp.diag(k_dense)
+                    if dense_matvec
+                    else jnp.full(n, kernel.theta**2, x.dtype)
+                )
+                M = jacobi(1.0 + sqrt_h * sqrt_h * diag_k)
+            elif spec.precond == "nystrom":
+                if k_sketch is None:
+                    key = (
+                        precond_key
+                        if precond_key is not None
+                        else jax.random.PRNGKey(0)
+                    )
+                    k_sketch = randomized_nystrom(
+                        k_mv,
+                        jnp.zeros(n, x.dtype),
+                        rank=spec.precond_rank,
+                        key=key,
+                    )
+                    sketch_matvecs = spec.precond_rank + 8
+                M = kernel_nystrom_preconditioner(
+                    k_sketch[0], k_sketch[1], sqrt_h
+                )
+            res = solve_jit(
+                a_op, b, spec, solve_state, x0=x_prev, M=M,
+                record_residuals=record_residuals,
+            )
+            solve_state = res.state
+            return res.x, res.info, int(spans.fetch(res.report.rung, WAIT))
+        if solver == "cg":
+            res = cg_jit(
+                a_op, b, x_prev,
+                tol=solver_tol, maxiter=solver_maxiter,
+                record_residuals=record_residuals,
+            )
+        elif solver == "defcg":
+            res = recycle.solve(
+                a_op, b, x_prev,
+                tol=solver_tol, maxiter=solver_maxiter,
+                record_residuals=record_residuals,
+            )
+        else:
+            raise ValueError(f"unknown solver={solver!r}")
+        return res.x, res.info, 0
+
     trace = NewtonTrace()
     psi_prev = -jnp.inf
     x_prev = None
     solve_time = 0.0
     converged = False
 
-    for it in range(max_newton):
-        sqrt_h, b, bg = newton_system(f, y, k_mv)
+    with spans.span("laplace.fit", fit=next(_fit_ids), systems=0) as fit:
+        for it in range(max_newton):
+            with spans.span("laplace.system", syncs=0):
+                with spans.span("laplace.newton_system"):
+                    sqrt_h, b, bg = newton_system(f, y, k_mv)
+                with spans.span("laplace.solve") as solved:
+                    xsol, info, rung = solve(sqrt_h, b, x_prev)
+                    spans.block(xsol, WAIT)
+                solve_time += solved.seconds
+                with spans.span("laplace.newton_step"):
+                    a_vec, f = newton_step(k_mv, sqrt_h, bg, xsol)
+                x_prev = xsol
 
-        t0 = time.perf_counter()
-        if solver == "cholesky":
-            amat = (
-                jnp.eye(n, dtype=x.dtype)
-                + sqrt_h[:, None] * k_dense * sqrt_h[None, :]
-            )
-            xsol = cholesky_solve(amat, b)
-            info = None
-        else:
-            if dense_matvec:
-                a_op = KernelSystemOperator(k_mv, sqrt_h)
-            else:
-                # x rides as a pytree leaf: a closure would bake the (n, d)
-                # data into every compiled solve as a constant.
-                a_op = RBFKernelSystemOperator(
-                    x, sqrt_h, kernel.theta, kernel.lengthscale, block, impl
-                )
-            if solver == "spec":
-                M = None
-                if spec.precond == "jacobi":
-                    # diag(A) = 1 + h_i k(x_i, x_i) — exact, host-free.
-                    diag_k = (
-                        jnp.diag(k_dense)
-                        if dense_matvec
-                        else jnp.full(n, kernel.theta**2, x.dtype)
+                logp_new, _, _ = logistic_quantities(f, y)
+                psi = logp_new - 0.5 * pt.vdot(a_vec, f)
+
+                trace.logp.append(float(spans.fetch(logp_new, WAIT)))
+                trace.psi.append(float(spans.fetch(psi, WAIT)))
+                trace.cumulative_time.append(solve_time)
+                fit.attrs["systems"] += 1
+                if info is not None:
+                    trace.solver_iterations.append(
+                        int(spans.fetch(info.iterations, WAIT))
                     )
-                    M = jacobi(1.0 + sqrt_h * sqrt_h * diag_k)
-                elif spec.precond == "nystrom":
-                    if k_sketch is None:
-                        key = (
-                            precond_key
-                            if precond_key is not None
-                            else jax.random.PRNGKey(0)
-                        )
-                        k_sketch = randomized_nystrom(
-                            k_mv,
-                            jnp.zeros(n, x.dtype),
-                            rank=spec.precond_rank,
-                            key=key,
-                        )
-                        sketch_matvecs = spec.precond_rank + 8
-                    M = kernel_nystrom_preconditioner(
-                        k_sketch[0], k_sketch[1], sqrt_h
+                    trace.solver_converged.append(
+                        bool(spans.fetch(info.converged, WAIT))
                     )
-                res = solve_jit(
-                    a_op, b, spec, solve_state, x0=x_prev, M=M,
-                    record_residuals=record_residuals,
-                )
-                solve_state = res.state
-            elif solver == "cg":
-                res = cg_jit(
-                    a_op, b, x_prev,
-                    tol=solver_tol, maxiter=solver_maxiter,
-                    record_residuals=record_residuals,
-                )
-            elif solver == "defcg":
-                res = recycle.solve(
-                    a_op, b, x_prev,
-                    tol=solver_tol, maxiter=solver_maxiter,
-                    record_residuals=record_residuals,
-                )
-            else:
-                raise ValueError(f"unknown solver={solver!r}")
-            xsol, info = res.x, res.info
-            rung = int(res.report.rung) if solver == "spec" else 0
-        jax.block_until_ready(xsol)
-        solve_time += time.perf_counter() - t0
+                    trace.solver_rungs.append(rung)
+                    # The one-off Nyström sketch cost is charged to the
+                    # system that built it — honest a-priori-subspace
+                    # accounting.
+                    trace.solver_matvecs.append(
+                        int(spans.fetch(info.matvecs, WAIT)) + sketch_matvecs
+                    )
+                    sketch_matvecs = 0
+                    if record_residuals and info.residual_norms is not None:
+                        trace.residual_traces.append(
+                            jnp.asarray(info.residual_norms)
+                        )
+                else:
+                    trace.solver_iterations.append(n)  # direct solve ≙ full rank
+                    trace.solver_converged.append(True)
+                    trace.solver_rungs.append(0)
+                    trace.solver_matvecs.append(0)
 
-        a_vec, f = newton_step(k_mv, sqrt_h, bg, xsol)
-        x_prev = xsol
+                if spans.fetch(jnp.abs(psi - psi_prev) < newton_tol, WAIT):
+                    converged = True
+                    break
+                psi_prev = psi
 
-        logp_new, _, _ = logistic_quantities(f, y)
-        psi = logp_new - 0.5 * pt.vdot(a_vec, f)
-
-        trace.logp.append(float(logp_new))
-        trace.psi.append(float(psi))
-        trace.cumulative_time.append(solve_time)
-        if info is not None:
-            trace.solver_iterations.append(int(info.iterations))
-            trace.solver_converged.append(bool(info.converged))
-            trace.solver_rungs.append(rung)
-            # The one-off Nyström sketch cost is charged to the system
-            # that built it — honest a-priori-subspace accounting.
-            trace.solver_matvecs.append(int(info.matvecs) + sketch_matvecs)
-            sketch_matvecs = 0
-            if record_residuals and info.residual_norms is not None:
-                trace.residual_traces.append(
-                    jnp.asarray(info.residual_norms)
-                )
-        else:
-            trace.solver_iterations.append(n)  # direct solve ≙ full rank
-            trace.solver_converged.append(True)
-            trace.solver_rungs.append(0)
-            trace.solver_matvecs.append(0)
-
-        if jnp.abs(psi - psi_prev) < newton_tol:
-            converged = True
-            break
-        psi_prev = psi
-
-    logp_final, _, _ = logistic_quantities(f, y)
+        logp_final, _, _ = logistic_quantities(f, y)
+        psi = float(spans.fetch(psi, WAIT))
+        logp_final = float(spans.fetch(logp_final, WAIT))
     return LaplaceResult(
-        f=f, psi=float(psi), logp=float(logp_final),
-        trace=trace, converged=converged,
+        f=f, psi=psi, logp=logp_final, trace=trace, converged=converged,
     )
 
 

@@ -1,4 +1,5 @@
-"""repro.runtime — fault-tolerant training loop."""
+"""repro.runtime — fault-tolerant training loop, and the program's spans
+and counters (:mod:`repro.runtime.spans`)."""
 
 from repro.runtime.trainer import Trainer, TrainerConfig, TrainerEvents
 

@@ -29,7 +29,8 @@ from typing import Dict
 
 @dataclasses.dataclass
 class TenantMetrics:
-    """Counters for one tenant key (all plain Python ints)."""
+    """Counters for one tenant key (plain Python ints, and the seconds
+    of queue wait)."""
 
     submitted: int = 0
     served: int = 0
@@ -39,6 +40,7 @@ class TenantMetrics:
     rung_retries: int = 0  # sum of adopted recovery-ladder rungs
     breakdowns: int = 0  # served systems with status >= BREAKDOWN
     queue_wait_ticks: int = 0  # ticks requests spent waiting pre-service
+    queue_wait_s: float = 0.0  # seconds from submit to the serving tick
     evictions: int = 0
     restores: int = 0  # warm re-admissions from a spilled state
     last_status: int = 0
@@ -95,6 +97,7 @@ class ServeMetrics:
         rung: int,
         status: int,
         waited_ticks: int,
+        waited_s: float,
         tick: int,
     ) -> None:
         t = self.tenant(key)
@@ -106,6 +109,7 @@ class ServeMetrics:
         if status >= 2:  # SolveStatus.BREAKDOWN_NONFINITE and above
             t.breakdowns += 1
         t.queue_wait_ticks += waited_ticks
+        t.queue_wait_s += waited_s
         t.last_status = status
         t.last_served_tick = tick
         self.served_total += 1

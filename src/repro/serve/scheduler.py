@@ -24,6 +24,15 @@ is one call to :meth:`SolveService.tick`:
    (:meth:`result` collects them), slot last-served ticks and the
    metrics registry update.
 
+Spans (:mod:`repro.runtime.spans`): each tick is a ``serve.tick`` span
+(attrs ``tick``, ``serving``, ``syncs``) holding ``serve.admit``,
+``serve.build_batch`` (batched ticks), ``serve.pool_step`` (dispatch
+only), two ``serve.fetch`` waits (the step's diagnostics) and
+``serve.scatter``.  Redeeming a ticket records a ``serve.ticket``
+interval from submit to redeem, with the serving tick's number and start
+and the scatter time; the same submit and tick start give each tenant's
+``queue_wait_s``.
+
 Nothing here blocks on a background thread: "continuous batching" is a
 property of the admission/eviction policy, not of concurrency — drive
 the loop with ``tick()`` / ``run_until_idle()`` / ``result(drive=True)``
@@ -41,6 +50,7 @@ operator closures module-stable exactly as with the plain front doors.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import OrderedDict, deque
 from typing import Any, Deque, Dict, Optional, Tuple
 
@@ -55,10 +65,14 @@ from repro.core import (
     solve_pool_step_jit,
 )
 from repro.core import pytree as pt
+from repro.runtime import spans
 from repro.serve.metrics import ServeMetrics
 from repro.serve.pool import PoolFullError, StatePool, TenantStateStore
 
 Pytree = Any
+
+# The tick's device reads wait in spans of this name.
+FETCH = "serve.fetch"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,6 +112,7 @@ class _Request:
     A: Any
     b: Pytree
     submitted_tick: int
+    submitted_ns: int
 
 
 class SolveService:
@@ -143,6 +158,9 @@ class SolveService:
         # considers waiting tenants in arrival order (first submit wins).
         self._pending: "OrderedDict[str, Deque[_Request]]" = OrderedDict()
         self._results: Dict[Tuple[str, int], ServedResult] = {}
+        # Ticket -> (submit, serving tick's start, scatter) on
+        # perf_counter_ns, until the ticket is redeemed.
+        self._stamps: Dict[Tuple[str, int], Tuple[int, int, int]] = {}
         self._seq: Dict[str, int] = {}
 
     # -- tenant-facing API -------------------------------------------------
@@ -161,7 +179,10 @@ class SolveService:
         if tenant not in self._pending:
             self._pending[tenant] = deque()
         self._pending[tenant].append(
-            _Request(ticket=ticket, A=A, b=b, submitted_tick=self.tick_count)
+            _Request(
+                ticket=ticket, A=A, b=b, submitted_tick=self.tick_count,
+                submitted_ns=time.perf_counter_ns(),
+            )
         )
         self.metrics.tenant(tenant).submitted += 1
         return ticket
@@ -178,7 +199,7 @@ class SolveService:
         """
         key = (ticket.tenant, ticket.seq)
         if key in self._results:
-            return self._results.pop(key)
+            return self._redeem(key)
         if not drive:
             raise KeyError(
                 f"ticket {ticket} not served yet (drive=False does not tick)"
@@ -186,7 +207,7 @@ class SolveService:
         for _ in range(self.max_drive_ticks):
             self.tick()
             if key in self._results:
-                return self._results.pop(key)
+                return self._redeem(key)
         raise RuntimeError(
             f"ticket {ticket} unresolved after {self.max_drive_ticks} ticks "
             "— was it submitted to this service?"
@@ -230,50 +251,64 @@ class SolveService:
         of systems served this tick (0 = idle tick)."""
         self.tick_count += 1
         tick = self.tick_count
-        self._admit(tick)
+        with spans.span("serve.tick", tick=tick, serving=0) as this_tick:
+            with spans.span("serve.admit"):
+                self._admit(tick)
 
-        serving = []  # (slot, request)
-        for tenant, q in self._pending.items():
-            if not q:
-                continue
-            slot = self.pool.slot_of(tenant)
-            if slot is not None:
-                serving.append((slot, q.popleft()))
-        self.metrics.record_tick(self.pool.occupancy, len(serving))
-        self.metrics.record_queue_depth(
-            sum(len(q) for q in self._pending.values()) + len(serving)
-        )
-        if not serving:
-            return 0
+            serving = []  # (slot, request)
+            for tenant, q in self._pending.items():
+                if not q:
+                    continue
+                slot = self.pool.slot_of(tenant)
+                if slot is not None:
+                    serving.append((slot, q.popleft()))
+            this_tick.attrs["serving"] = len(serving)
+            self.metrics.record_tick(self.pool.occupancy, len(serving))
+            self.metrics.record_queue_depth(
+                sum(len(q) for q in self._pending.values()) + len(serving)
+            )
+            if not serving:
+                return 0
 
-        if len(serving) == 1:
-            # B=1 fence: one active slot loses under the vmapped masked
-            # while-loop — gather the slot and run the plain front door.
-            slot, req = serving[0]
-            res = solve_jit(
-                req.A, req.b, self.spec, self.pool.slot_state(slot)
-            )
-            self.pool.write_slot(slot, res.state)
-            self.metrics.single_steps += 1
-            self._scatter(req, res.x, res.info, res.report, tick)
-        else:
-            systems, b_batch, active = self._build_batch(serving)
-            res = solve_pool_step_jit(
-                systems, b_batch, self.spec, self.pool.state, active
-            )
-            self.pool.state = res.state
-            self.metrics.batched_steps += 1
-            info = jax.device_get(res.info._replace(residual_norms=None))
-            report = jax.device_get(res.report)
-            for slot, req in serving:
-                self._scatter(
-                    req,
-                    jax.tree_util.tree_map(lambda l: l[slot], res.x),
-                    jax.tree_util.tree_map(lambda l: l[slot], info),
-                    jax.tree_util.tree_map(lambda l: l[slot], report),
-                    tick,
-                )
-        self.pool.touch([slot for slot, _ in serving], tick)
+            if len(serving) == 1:
+                # B=1 fence: one active slot loses under the vmapped
+                # masked while-loop — gather the slot and run the plain
+                # front door.
+                slot, req = serving[0]
+                with spans.span("serve.pool_step"):
+                    res = solve_jit(
+                        req.A, req.b, self.spec, self.pool.slot_state(slot)
+                    )
+                    self.pool.write_slot(slot, res.state)
+                self.metrics.single_steps += 1
+            else:
+                with spans.span("serve.build_batch"):
+                    systems, b_batch, active = self._build_batch(serving)
+                with spans.span("serve.pool_step"):
+                    res = solve_pool_step_jit(
+                        systems, b_batch, self.spec, self.pool.state, active
+                    )
+                    self.pool.state = res.state
+                self.metrics.batched_steps += 1
+            info = spans.fetch(res.info._replace(residual_norms=None), FETCH)
+            report = spans.fetch(res.report, FETCH)
+            with spans.span("serve.scatter"):
+                if len(serving) == 1:
+                    self._scatter(
+                        serving[0][1], res.x, info, report, tick,
+                        this_tick.start_ns,
+                    )
+                else:
+                    for slot, req in serving:
+                        self._scatter(
+                            req,
+                            jax.tree_util.tree_map(lambda l: l[slot], res.x),
+                            jax.tree_util.tree_map(lambda l: l[slot], info),
+                            jax.tree_util.tree_map(lambda l: l[slot], report),
+                            tick,
+                            this_tick.start_ns,
+                        )
+                self.pool.touch([slot for slot, _ in serving], tick)
         return len(serving)
 
     # -- internals ---------------------------------------------------------
@@ -335,7 +370,22 @@ class SolveService:
         b_batch = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *bs)
         return systems, b_batch, jnp.asarray(active)
 
-    def _scatter(self, req: _Request, x, info, report, tick: int) -> None:
+    def _redeem(self, key: Tuple[str, int]) -> ServedResult:
+        """Hand out a served result and record its ticket: the
+        ``serve.ticket`` interval runs from submit to now, with the serving
+        tick's number and start and the scatter time as attrs."""
+        served = self._results.pop(key)
+        submit_ns, tick_start_ns, scatter_ns = self._stamps.pop(key)
+        spans.interval(
+            "serve.ticket", submit_ns, time.perf_counter_ns(),
+            tick=served.tick, tick_start_ns=tick_start_ns,
+            scatter_ns=scatter_ns,
+        )
+        return served
+
+    def _scatter(
+        self, req: _Request, x, info, report, tick: int, tick_start_ns: int
+    ) -> None:
         waited = max(tick - 1 - req.submitted_tick, 0)
         served = ServedResult(
             tenant=req.ticket.tenant,
@@ -357,7 +407,11 @@ class SolveService:
                 matvecs=np.int32(info.matvecs),
             ),
         )
-        self._results[(req.ticket.tenant, req.ticket.seq)] = served
+        key = (req.ticket.tenant, req.ticket.seq)
+        self._results[key] = served
+        self._stamps[key] = (
+            req.submitted_ns, tick_start_ns, time.perf_counter_ns()
+        )
         self.metrics.record_served(
             req.ticket.tenant,
             iterations=served.iterations,
@@ -366,6 +420,7 @@ class SolveService:
             rung=served.rung,
             status=served.status,
             waited_ticks=waited,
+            waited_s=(tick_start_ns - req.submitted_ns) * 1e-9,
             tick=tick,
         )
 
