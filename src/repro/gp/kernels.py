@@ -3,8 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
-
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
@@ -34,12 +33,71 @@ class RBFKernel:
 
     def matvec_fn(
         self, x: jnp.ndarray, *, impl: str = "auto", block: int = 256
-    ) -> Callable[[jnp.ndarray], jnp.ndarray]:
+    ) -> "GramMatvec":
         """Matrix-free ``v ↦ K v`` over the fused kernel (K never built)."""
+        return GramMatvec(x, self.theta, self.lengthscale, impl, block)
 
-        def mv(v: jnp.ndarray) -> jnp.ndarray:
-            return kops.rbf_matvec(
-                x, v, self.theta, self.lengthscale, impl=impl, block=block
-            )
 
+@jax.tree_util.register_pytree_node_class
+class GramMatvec:
+    """``v ↦ K(X, X) v`` over the fused RBF kernel, as a pytree callable.
+
+    ``x``, ``theta`` and ``lengthscale`` are leaves and ``impl``/``block``
+    static aux data, so a jitted function that takes it as an argument
+    compiles once per shape for every data set and hyperparameter of that
+    shape, and never bakes ``x`` into its executable as a constant.
+    ``v`` may be ``(n,)`` or column-stacked ``(n, r)``.
+
+    The Gram function (``kernels.ops.rbf_matvec``) is bound when the
+    matvec is made and kept in the aux data too: a jitted caller's cache
+    is keyed on the function it traced, so one that replaces the module's
+    function (a fault-injection test) is traced anew, not served a stale
+    executable.
+    """
+
+    __slots__ = ("x", "theta", "lengthscale", "impl", "block", "gram")
+
+    def __init__(self, x, theta, lengthscale, impl: str = "auto",
+                 block: int = 256):
+        self.x, self.theta, self.lengthscale = x, theta, lengthscale
+        self.impl, self.block = impl, block
+        self.gram = kops.rbf_matvec
+
+    def __call__(self, v: jnp.ndarray) -> jnp.ndarray:
+        return self.gram(
+            self.x, v, self.theta, self.lengthscale,
+            impl=self.impl, block=self.block,
+        )
+
+    def tree_flatten(self):
+        return (self.x, self.theta, self.lengthscale), (
+            self.impl, self.block, self.gram,
+        )
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        mv = cls.__new__(cls)
+        mv.x, mv.theta, mv.lengthscale = leaves
+        mv.impl, mv.block, mv.gram = aux
         return mv
+
+
+@jax.tree_util.register_pytree_node_class
+class DenseMatvec:
+    """``v ↦ K v`` over a materialized ``K``, as a pytree callable whose
+    one leaf is ``K``."""
+
+    __slots__ = ("k",)
+
+    def __init__(self, k: jnp.ndarray):
+        self.k = k
+
+    def __call__(self, v: jnp.ndarray) -> jnp.ndarray:
+        return self.k @ v
+
+    def tree_flatten(self):
+        return (self.k,), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves)

@@ -28,6 +28,7 @@ from typing import Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import (
     KernelSystemOperator,
@@ -42,7 +43,7 @@ from repro.core import pytree as pt
 from repro.core.api import solve_jit
 from repro.core.operators import RBFKernelSystemOperator
 from repro.core.solvers import cg_jit
-from repro.gp.kernels import RBFKernel
+from repro.gp.kernels import DenseMatvec, RBFKernel
 from repro.runtime import spans
 
 # Every blocking read of a fit waits in a span of this name.
@@ -63,12 +64,15 @@ def logistic_quantities(f: jnp.ndarray, y: jnp.ndarray):
     return logp, grad, hdiag
 
 
+@jax.jit
 def newton_system(f: jnp.ndarray, y: jnp.ndarray, k_mv: Callable):
     """The Newton system at latent ``f`` (paper Eq. 9–10).
 
     Returns ``(sqrt_h, b, bg)``: the operator is ``A = I + H½ K H½``, the
     right-hand side ``b = H½ K bg`` with ``bg = H f + ∇ log p(y|f)``, and
-    ``k_mv`` applies ``K``.
+    ``k_mv`` applies ``K``.  One compiled program; ``k_mv`` is a pytree
+    callable (:class:`repro.gp.kernels.GramMatvec` or ``DenseMatvec``)
+    whose data are arguments, not constants.
     """
     _, grad, hdiag = logistic_quantities(f, y)
     sqrt_h = jnp.sqrt(hdiag)
@@ -76,11 +80,28 @@ def newton_system(f: jnp.ndarray, y: jnp.ndarray, k_mv: Callable):
     return sqrt_h, sqrt_h * k_mv(bg), bg
 
 
+@jax.jit
 def newton_step(k_mv: Callable, sqrt_h, bg, sol):
     """``(a, f)`` from the solution ``sol`` of :func:`newton_system`:
-    ``a = bg − H½ sol`` and the next latent ``f = K a``."""
+    ``a = bg − H½ sol`` and the next latent ``f = K a``.  One compiled
+    program, like :func:`newton_system`."""
     a_vec = bg - sqrt_h * sol
     return a_vec, k_mv(a_vec)
+
+
+@jax.jit
+def _readout(f, y, a_vec, psi_prev, newton_tol, counts):
+    """A Newton system's scalars in one array, for one device read.
+
+    Returns ``(row, psi)``: ``row`` is ``[log p(y|f), Ψ, |Ψ − Ψ_prev| <
+    newton_tol, *counts]`` in ``f``'s dtype, ``psi`` the next system's
+    ``psi_prev`` (left on the device).
+    """
+    logp, _, _ = logistic_quantities(f, y)
+    psi = logp - 0.5 * pt.vdot(a_vec, f)
+    done = jnp.abs(psi - psi_prev) < newton_tol
+    row = [logp, psi, done] + [jnp.asarray(c) for c in counts]
+    return jnp.stack([v.astype(f.dtype) for v in row]), psi
 
 
 @dataclasses.dataclass
@@ -166,10 +187,11 @@ def laplace_gpc(
     ``fit``, a process-wide id, ``systems`` and ``syncs``) holds one
     ``laplace.system`` per Newton system, which holds
     ``laplace.newton_system``, ``laplace.solve`` and
-    ``laplace.newton_step``.  Every device read is one ``laplace.wait``
-    span: on the spec path 8 per system (the rung, the solution, log p,
-    Ψ, iterations, converged, matvecs, the ΔΨ test) and 2 per fit (the
-    returned Ψ and log p).
+    ``laplace.newton_step``, each one compiled program.  Every device
+    read is one ``laplace.wait`` span: 2 per system (the solution, inside
+    ``laplace.solve``, and one readout of log p, Ψ, the ΔΨ test and the
+    solve's iterations, converged, matvecs and rung) and none per fit (the
+    returned Ψ and log p are the last readout's).
     """
     n = x.shape[0]
     f = jnp.zeros(n, x.dtype)
@@ -184,7 +206,7 @@ def laplace_gpc(
     if (solver == "cholesky" or dense_matvec) and k_dense is None:
         k_dense = kernel.gram(x)
     if dense_matvec:
-        k_mv = lambda v: k_dense @ v  # noqa: E731 — stable closure for jit
+        k_mv = DenseMatvec(k_dense)
     else:
         k_mv = kernel.matvec_fn(x, impl=impl, block=block)
     if solver == "defcg" and recycle is None:
@@ -242,7 +264,7 @@ def laplace_gpc(
                 record_residuals=record_residuals,
             )
             solve_state = res.state
-            return res.x, res.info, int(spans.fetch(res.report.rung, WAIT))
+            return res.x, res.info, res.report.rung
         if solver == "cg":
             res = cg_jit(
                 a_op, b, x_prev,
@@ -260,7 +282,7 @@ def laplace_gpc(
         return res.x, res.info, 0
 
     trace = NewtonTrace()
-    psi_prev = -jnp.inf
+    psi_prev = np.asarray(-np.inf, x.dtype)
     x_prev = None
     solve_time = 0.0
     converged = False
@@ -278,27 +300,26 @@ def laplace_gpc(
                     a_vec, f = newton_step(k_mv, sqrt_h, bg, xsol)
                 x_prev = xsol
 
-                logp_new, _, _ = logistic_quantities(f, y)
-                psi = logp_new - 0.5 * pt.vdot(a_vec, f)
-
-                trace.logp.append(float(spans.fetch(logp_new, WAIT)))
-                trace.psi.append(float(spans.fetch(psi, WAIT)))
+                counts = () if info is None else (
+                    info.iterations, info.converged, info.matvecs, rung
+                )
+                row, psi_prev = _readout(
+                    f, y, a_vec, psi_prev, newton_tol, counts
+                )
+                row = spans.fetch(row, WAIT)
+                trace.logp.append(float(row[0]))
+                trace.psi.append(float(row[1]))
                 trace.cumulative_time.append(solve_time)
                 fit.attrs["systems"] += 1
                 if info is not None:
-                    trace.solver_iterations.append(
-                        int(spans.fetch(info.iterations, WAIT))
-                    )
-                    trace.solver_converged.append(
-                        bool(spans.fetch(info.converged, WAIT))
-                    )
-                    trace.solver_rungs.append(rung)
+                    iterations, conv, matvecs, rung = row[3:]
+                    trace.solver_iterations.append(int(iterations))
+                    trace.solver_converged.append(bool(conv))
+                    trace.solver_rungs.append(int(rung))
                     # The one-off Nyström sketch cost is charged to the
                     # system that built it — honest a-priori-subspace
                     # accounting.
-                    trace.solver_matvecs.append(
-                        int(spans.fetch(info.matvecs, WAIT)) + sketch_matvecs
-                    )
+                    trace.solver_matvecs.append(int(matvecs) + sketch_matvecs)
                     sketch_matvecs = 0
                     if record_residuals and info.residual_norms is not None:
                         trace.residual_traces.append(
@@ -310,16 +331,14 @@ def laplace_gpc(
                     trace.solver_rungs.append(0)
                     trace.solver_matvecs.append(0)
 
-                if spans.fetch(jnp.abs(psi - psi_prev) < newton_tol, WAIT):
+                if row[2]:
                     converged = True
                     break
-                psi_prev = psi
 
-        logp_final, _, _ = logistic_quantities(f, y)
-        psi = float(spans.fetch(psi, WAIT))
-        logp_final = float(spans.fetch(logp_final, WAIT))
+    # Log p at the final f is the last readout's.
     return LaplaceResult(
-        f=f, psi=psi, logp=logp_final, trace=trace, converged=converged,
+        f=f, psi=trace.psi[-1], logp=trace.logp[-1], trace=trace,
+        converged=converged,
     )
 
 
