@@ -18,9 +18,12 @@ One chip runs, in one process and through the normal entry points:
 Every solve must converge on its first attempt: a recovery-ladder rung
 above 0, or more matvecs than a clean attempt costs, fails the run.
 
-``--chips 4`` runs only the mesh phase: two related systems solved over a
-4-chip ``"solve"`` mesh, compared with the same solves on one device, and
-the one-all-reduce-per-iteration contract read from the compiled HLO.
+``--chips 4`` runs only the mesh phase: the same Newton sequence through
+``laplace_gpc(..., mesh=make_solve_mesh(4))`` at n = 2^17 (the data's
+rows, the latent and the recycled basis split over a 4-chip ``"solve"``
+mesh, every system solved by the sharded def-CG and the driver's Gram
+passes split over the chips), held to the same checks, and the
+one-all-reduce-per-iteration contract read from the compiled HLO.
 
 The chip path is float32 with x64 off: the Mosaic kernels take no 64-bit
 types.  The script fails (non-zero exit, no result line) when JAX finds no
@@ -132,7 +135,7 @@ def phase_kernel_check(x, cfg):
         check(err <= MATVEC_RTOL, f"rbf_matvec impl={impl} error {err}")
 
 
-def newton_sequence(x, y, cfg, impl):
+def newton_sequence(x, y, cfg, impl, mesh=None):
     from repro.core import SolveSpec
     from repro.gp import RBFKernel, laplace_gpc
 
@@ -143,7 +146,7 @@ def newton_sequence(x, y, cfg, impl):
     res = laplace_gpc(
         x, y, RBFKernel(theta=cfg.theta, lengthscale=cfg.lengthscale),
         spec=spec, newton_tol=cfg.newton_tol, max_newton=cfg.max_newton,
-        impl=impl, block=cfg.block, dense_matvec=False,
+        impl=impl, block=cfg.block, dense_matvec=False, mesh=mesh,
     )
     tr = res.trace
     solve_s = np.diff([0.0] + tr.cumulative_time).tolist()
@@ -287,77 +290,34 @@ def run_one_chip(clock):
 
 def run_mesh(clock, n_chips):
     from repro.configs.gpc_mnist import CONFIG as cfg
-    from repro.core import SolveSpec, sharded, solve, solve_jit
+    from repro.core import SolveSpec, sharded
+    from repro.core.operators import RBFKernelSystemOperator
     from repro.launch import hlo_stats
     from repro.launch.mesh import make_solve_mesh
 
     check(len(jax.devices()) >= n_chips,
           f"{n_chips} chips asked for, JAX sees {len(jax.devices())}")
-    spec = SolveSpec(
-        method="defcg", k=cfg.k, ell=cfg.ell, tol=cfg.tol,
-        maxiter=cfg.maxiter, recovery_rungs=0,
-    )
-    # Both sides run the same f32 def-CG with no recovery ladder (the
-    # sharded path has none), so a fallback cannot hide a failed attempt.
+    mesh = make_solve_mesh(n_chips)
     print(f"mesh phase: n={N_MESH} d={cfg.d} chips={n_chips} "
           f"defcg(k={cfg.k}, ell={cfg.ell}) tol={cfg.tol} dtype=float32",
           flush=True)
     with Phase("data", clock):
         x, y = digits(N_MESH, SEED)
-    mesh = make_solve_mesh(n_chips)
-    # Both runs solve the same two systems: the first two Newton systems,
-    # the second built from the one-device solution of the first.
-    a1, b1, bg1 = newton_system(x, y, jnp.zeros_like(y), cfg)
-    runs, true_res = {}, {}
-    for name in ("one_device", "sharded"):
-        with Phase(f"solve_{name}", clock):
-            if name == "one_device":
-                r1 = solve_jit(a1, b1, spec, None)
-                a2, b2, _ = newton_system(
-                    x, y, newton_step(x, cfg, a1, bg1, r1.x), cfg
-                )
-                r2 = solve_jit(a2, b2, spec, r1.state)
-            else:
-                r1 = solve(a1, b1, spec, None, mesh=mesh)
-                r2 = solve(a2, b2, spec, r1.state, mesh=mesh)
-            jax.block_until_ready((r1.x, r2.x))
-        its = [int(r.info.iterations) for r in (r1, r2)]
-        mvs = [int(r.info.matvecs) for r in (r1, r2)]
-        conv = [bool(r.info.converged) for r in (r1, r2)]
-        # Recurrence vs true relative residuals: a gap between them is an
-        # inexact operator or a deflation that lost A-orthogonality.
-        rel = [
-            (float(r.info.residual_norm) / float(jnp.linalg.norm(b)),
-             float(jnp.linalg.norm(b - a.matvec(r.x)) / jnp.linalg.norm(b)))
-            for r, a, b in ((r1, a1, b1), (r2, a2, b2))
-        ]
-        print(f"  {name}: iterations={its} matvecs={mvs} converged={conv} "
-              f"(recurrence, true) residuals={rel}", flush=True)
-        check(all(conv), f"{name}: a system did not converge")
-        check(its[1] < its[0], f"{name}: recycled system not faster {its}")
-        runs[name] = (r1, r2)
-        true_res[name] = [t for _, t in rel]
-    for r in runs["sharded"]:
-        devs = {s.device for s in r.x.addressable_shards}
-        print(f"  sharded x: {r.x.sharding} on devices "
-              f"{sorted(d.id for d in devs)}", flush=True)
-        check(len(devs) == n_chips, f"x lives on {len(devs)} devices")
-    # A = I + H½KH½ has every eigenvalue ≥ 1, so two solutions whose
-    # residuals are below tol·‖b‖ differ by at most 2·tol·‖b‖.
-    for i, b in enumerate((b1, b2)):
-        xs, x1 = (np.asarray(runs[k][i].x, np.float64)
-                  for k in ("sharded", "one_device"))
-        diff = np.linalg.norm(xs - x1)
-        bound = 2.0 * cfg.tol * float(jnp.linalg.norm(b))
-        print(f"  system {i + 1}: |x_sharded - x_one_device|={diff} "
-              f"(bound {bound}) rel={diff / np.linalg.norm(x1)}", flush=True)
-        check(diff <= bound, f"system {i + 1}: sharded x differs by {diff}")
-        res = true_res["sharded"][i]
-        check(res <= 2.0 * cfg.tol,
-              f"system {i + 1}: sharded true residual {res}")
+    # The sharded engine has no recovery ladder: every system must
+    # converge on its first attempt (newton_sequence's checks).
+    with Phase("gp_newton_mesh", clock):
+        res = newton_sequence(x, y, cfg, "auto", mesh=mesh)
+    devs = {s.device for s in res.f.addressable_shards}
+    print(f"  f: {res.f.sharding} on devices {sorted(d.id for d in devs)}",
+          flush=True)
+    check(len(devs) == n_chips, f"f lives on {len(devs)} devices")
     with Phase("hlo_collectives", clock):
-        low = sharded.lower_sharded(a2, b2, spec, runs["sharded"][0].state,
-                                    mesh=mesh)
+        spec = SolveSpec(method="defcg", k=cfg.k, ell=cfg.ell, tol=cfg.tol,
+                         maxiter=cfg.maxiter)
+        a = RBFKernelSystemOperator(
+            x, jnp.full_like(y, 0.5), cfg.theta, cfg.lengthscale, cfg.block
+        )
+        low = sharded.lower_sharded(a, y, spec, None, mesh=mesh)
         per_body = hlo_stats.while_body_collectives(low.compile().as_text())
     print(f"  while-body collectives: {per_body}", flush=True)
     # Loops without collectives (scans inside the matvec) are not Krylov

@@ -192,10 +192,12 @@ def psum_merged(parts, axis_name: str):
     §5): every scalar reduction of an iteration must ride this ONE
     collective — the HLO collective-counting pass
     (:func:`repro.launch.hlo_stats.while_body_collectives`) pins it.
+    The collective carries the named scope ``sharded.psum``.
     """
     flats = [jnp.ravel(jnp.asarray(p)) for p in parts]
     packed = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
-    red = jax.lax.psum(packed, axis_name)
+    with jax.named_scope("sharded.psum"):
+        red = jax.lax.psum(packed, axis_name)
     out, off = [], 0
     for p, f in zip(parts, flats):
         out.append(jnp.reshape(red[off : off + f.shape[0]], jnp.shape(p)))

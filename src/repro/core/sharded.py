@@ -10,8 +10,8 @@ ops (:mod:`repro.kernels.ops`) applied per-shard.
 
 The communication contract is ONE collective per def-CG iteration: all
 scalar reductions of a step — ``pᵀAp``, ``rᵀAp``, ``ApᵀAp``, the
-deflation GEMVs ``(AW)ᵀAp`` / ``(AW)ᵀr``, and a FRESH ``‖r‖²`` of the
-incoming residual — are packed into a single
+deflation GEMVs ``(AW)ᵀAp`` / ``(AW)ᵀr`` / ``Wᵀr`` / ``WᵀAp``, and a
+FRESH ``‖r‖²`` of the incoming residual — are packed into a single
 :func:`repro.core.engine.psum_merged` all-reduce.  The post-update
 quantities then follow from one-step recurrences
 
@@ -43,6 +43,12 @@ sharded natively:
   local K-tile rows on the fly via
   :func:`repro.kernels.ops.rbf_matvec_rect` — ``K`` is never
   materialized, which is what lets n = 10⁵–10⁶ GP solves run at all.
+
+The collectives carry named scopes for the device trace (metadata only):
+``sharded.gather_x`` (the data), ``sharded.gather_v`` (a vector or basis)
+and ``sharded.psum`` (the merged all-reduce,
+:func:`repro.core.engine.psum_merged`).  :class:`repro.gp.kernels.GramMatvec`
+uses the same two gathers for the GP driver's own Gram passes.
 
 Differences from the unsharded front door (documented, tested):
 
@@ -77,6 +83,7 @@ from repro.core import operators as ops_mod
 from repro.core import pytree as pt
 from repro.core.engine import SolveInfo, SolveStatus
 from repro.core.recycle import RecycleState
+from repro.core.solvers import redeflate
 from repro.core.strategies import HarmonicRitz, extract_next_basis_core
 from repro.kernels import ops as kops
 
@@ -195,6 +202,20 @@ def _plan_operator(A, *, need_adjoint: bool):
     )
 
 
+def gather_x(x_loc):
+    """The whole data from each shard's row block: an all-gather under
+    the named scope ``sharded.gather_x``."""
+    with jax.named_scope("sharded.gather_x"):
+        return jax.lax.all_gather(x_loc, SOLVE_AXIS, tiled=True)
+
+
+def gather_v(v_loc, axis: int = 0):
+    """A whole vector (or basis, ``axis=1``) from each shard's slice: an
+    all-gather under the named scope ``sharded.gather_v``."""
+    with jax.named_scope("sharded.gather_v"):
+        return jax.lax.all_gather(v_loc, SOLVE_AXIS, axis=axis, tiled=True)
+
+
 def _make_applies(kind: str, aux, leaves):
     """Build the per-shard ``(apply, rapply, basis_apply)`` closures.
 
@@ -204,27 +225,23 @@ def _make_applies(kind: str, aux, leaves):
     a loop constant XLA hoists, so it happens once per solve, not per
     iteration.
     """
-    ax = SOLVE_AXIS
     if kind == "dense":
         mat_loc = leaves[0]
 
         def apply(v_loc):
-            v_full = jax.lax.all_gather(v_loc, ax, tiled=True)
-            return pt.matmul(mat_loc, v_full)
+            return pt.matmul(mat_loc, gather_v(v_loc))
 
         if len(leaves) > 1:
             mat_t_loc = leaves[1]
 
             def rapply(u_loc):
-                u_full = jax.lax.all_gather(u_loc, ax, tiled=True)
-                return pt.matmul(mat_t_loc, u_full)
+                return pt.matmul(mat_t_loc, gather_v(u_loc))
 
         else:
             rapply = apply
 
         def basis_apply(w_loc):  # (k, n_loc) -> (k, n_loc)
-            w_full = jax.lax.all_gather(w_loc, ax, axis=1, tiled=True)
-            return pt.matmul(w_full, mat_loc.T)
+            return pt.matmul(gather_v(w_loc, axis=1), mat_loc.T)
 
         return apply, rapply, basis_apply
 
@@ -234,10 +251,10 @@ def _make_applies(kind: str, aux, leaves):
         # Gathered ONCE per solve (closure constant, hoisted out of the
         # while loop) — each shard then owns the rectangular tile
         # (local rows × all columns) of K implicitly.
-        x_full = jax.lax.all_gather(x_loc, ax, tiled=True)
+        x_full = gather_x(x_loc)
 
         def apply(v_loc):
-            u_full = jax.lax.all_gather(sh_loc * v_loc, ax, tiled=True)
+            u_full = gather_v(sh_loc * v_loc)
             kv_loc = kops.rbf_matvec_rect(
                 x_loc, x_full, u_full, theta, lengthscale,
                 impl=impl, block=block,
@@ -245,9 +262,7 @@ def _make_applies(kind: str, aux, leaves):
             return v_loc + sh_loc * kv_loc
 
         def basis_apply(w_loc):  # (k, n_loc): one fused multi-RHS pass
-            u_full = jax.lax.all_gather(
-                w_loc * sh_loc[None, :], ax, axis=1, tiled=True
-            )
+            u_full = gather_v(w_loc * sh_loc[None, :], axis=1)
             kv_loc = kops.rbf_matvec_rect(
                 x_loc, x_full, u_full.T, theta, lengthscale,
                 impl=impl, block=block,
@@ -371,11 +386,12 @@ def _sharded_defcg_body(
     """Deflated CG + harmonic-Ritz extraction on per-shard state.
 
     The iteration's ONE all-reduce merges ``[pᵀAp, rᵀAp, ApᵀAp,
-    (AW)ᵀAp, ‖r‖², (AW)ᵀr]`` — fresh reductions of the incoming
-    residual plus the Ap products; the post-update ``‖r₊‖²`` /
-    ``(AW)ᵀr₊`` that β and μ need come from one-step recurrences off
-    those fresh values, so μ and β need no second collective and
-    recurrence rounding never accumulates.
+    (AW)ᵀAp, ‖r‖², (AW)ᵀr, Wᵀr, WᵀAp]`` — fresh reductions of the
+    incoming residual plus the Ap products; the post-update ``‖r₊‖²`` /
+    ``(AW)ᵀr₊`` / ``Wᵀr₊`` that β, μ and the re-deflation
+    (:func:`repro.core.solvers.redeflate`) need come from one-step
+    recurrences off those fresh values, so they need no second
+    collective and recurrence rounding never accumulates.
     """
     ax = SOLVE_AXIS
 
@@ -399,9 +415,9 @@ def _sharded_defcg_body(
         # -- setup: WᵀAW factor + deflated initial guess -----------------
         r_init = b_loc - apply(x0_loc)
         matvecs = matvecs + 1
-        waw, bsq, wr = engine.psum_merged(
+        waw, bsq, wr, awaw = engine.psum_merged(
             [pt.matmul(w_loc, aw_used.T), pt.vdot(b_loc, b_loc),
-             pt.matmul(w_loc, r_init)],
+             pt.matmul(w_loc, r_init), pt.matmul(aw_used, aw_used.T)],
             ax,
         )
         bnorm = jnp.sqrt(bsq)
@@ -444,9 +460,9 @@ def _sharded_defcg_body(
                 ap = apply(p)
             rap_l, awap_l = kops.fused_rz_reduce(r, ap, aw_used)
             rs_l, awr_l = kops.fused_rz_reduce(r, r, aw_used)
-            d, rap, apap, awap, rs, awr = engine.psum_merged(
+            d, rap, apap, awap, rs, awr, wr, wap = engine.psum_merged(
                 [pt.vdot(p, ap), rap_l, pt.vdot(ap, ap), awap_l,
-                 rs_l, awr_l],
+                 rs_l, awr_l, pt.matmul(w_loc, r), pt.matmul(w_loc, ap)],
                 ax,
             )
             bad, code = engine.classify_breakdown(d, rnorm, diverged_at)
@@ -457,6 +473,7 @@ def _sharded_defcg_body(
             rap = jnp.where(bad, 0.0, rap)
             apap = jnp.where(bad, 0.0, apap)
             awap = jnp.where(bad, 0.0, awap)
+            wap = jnp.where(bad, 0.0, wap)
             alpha = jnp.where(
                 bad | (~active), 0.0, rs / jnp.where(bad, 1.0, d)
             )
@@ -465,6 +482,13 @@ def _sharded_defcg_body(
                 rs - 2.0 * alpha * rap + alpha * alpha * apap, 0.0
             )
             awr_new = awr - alpha * awap
+            if refresh_aw != "stale":
+                # Take the rounding-born W-part out of the new residual
+                # (solvers.redeflate); Wᵀr and WᵀAp rode the all-reduce.
+                x, r, rs_new, awr_new, _ = redeflate(
+                    x, r, wr - alpha * wap, awr_new, rs_new, w_loc,
+                    aw_used, winv, awaw, active & (~bad),
+                )
             mu = pt.matmul(winv, awr_new.astype(winv.dtype))
             beta = rs_new / jnp.where(rs == 0.0, 1.0, rs)
             p_new, _, _ = kops.fused_deflate_direction(

@@ -242,6 +242,39 @@ def deflated_initial_guess(x_prev, r_prev, W, AW, waw_cho):
     return x0, r0
 
 
+def redeflate(x, r, wr, awr, rs, W, AW, waw_inv, awaw, keep):
+    """Line 3 of Alg. 1 again, inside the loop: ``x += W c``, ``r −= AW c``
+    with ``c = (WᵀAW)⁻¹ Wᵀr``, flat ``(k, n)`` bases.
+
+    ``wr`` is ``Wᵀr`` and ``awr``, ``rs`` the caller's ``(AW)ᵀr`` and
+    ``‖r‖²``; they come back updated to the new ``r`` through the k×k
+    ``awaw = (AW)ᵀAW`` (no further pass over ``r``).  Nothing is applied
+    where ``keep`` is False (a frozen or broken step), nor while
+    ``‖Wᵀr‖ ≤ √eps·‖r‖``: that small a part moves α by its square and the
+    stopping test not at all, and leaving it keeps the iterates those of
+    plain def-CG.  Returns ``(x, r, rs, awr, c)``.  Only for an exact
+    ``AW``: with stale products ``AW c`` is not ``A W c``, and the
+    recurrence would leave the true residual.
+
+    In exact arithmetic def-CG keeps ``Wᵀr = 0`` and ``c`` is zero.  In
+    float32 each step's rounding leaves a part of ``r`` along ``W`` that
+    no later direction removes, since every direction is A-orthogonal to
+    ``W``.  It grows with the basis' Ritz values, so with n; once the
+    rest of ``r`` falls below it, ``α = ‖r‖²/pᵀAp`` overshoots (``rᵀp =
+    ‖r‖² − (Wᵀr)ᵀμ``) and the residual grows again.  On a v5e the GP
+    Newton systems' warm solves diverged so from n = 2^16 on (PERF.md);
+    taking ``c`` out every step keeps them converging.
+    """
+    keep = keep & (pt.vdot(wr, wr) > jnp.finfo(r.dtype).eps * rs)
+    c = jnp.where(keep, pt.matmul(waw_inv, wr.astype(waw_inv.dtype)), 0.0)
+    mc = pt.matmul(awaw, c)
+    c_r = c.astype(r.dtype)
+    x = x + pt.matmul(c_r, W)
+    r = r - pt.matmul(c_r, AW)
+    rs = jnp.maximum(rs - 2.0 * pt.vdot(c, awr) + pt.vdot(c, mc), 0.0)
+    return x, r, rs.astype(awr.dtype), awr - mc.astype(awr.dtype), c
+
+
 def defcg(
     A,
     b: Pytree,
@@ -475,6 +508,7 @@ def defcg(
         if waw_inv is None:  # exact or unguarded-stale setup
             z_flat = precond(r_flat) if precond is not None else r_flat
             p_flat, waw_inv = _post_guess(aw_flat, waw_cho, z_flat)
+        awaw = pt.gram(aw_flat, aw_flat)
     else:
         r_flat = b_flat - A_flat(x_flat)
         matvecs = matvecs + 1
@@ -528,6 +562,11 @@ def defcg(
                 x, r, rs_new, awr = kops.fused_cg_update(
                     x, r, p, ap, alpha, aw_flat
                 )
+                if exact_aw:
+                    x, r, rs_new, awr, _ = redeflate(
+                        x, r, pt.matmul(w_flat, r), awr, rs_new, w_flat,
+                        aw_flat, waw_inv, awaw, active & (~bad),
+                    )
                 mu = pt.matmul(waw_inv, awr.astype(waw_inv.dtype))
             else:
                 x, r, rs_new, _ = kops.fused_cg_update(x, r, p, ap, alpha)

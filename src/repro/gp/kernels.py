@@ -3,9 +3,12 @@
 from __future__ import annotations
 
 import dataclasses
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.core import sharded
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
@@ -32,10 +35,12 @@ class RBFKernel:
         )
 
     def matvec_fn(
-        self, x: jnp.ndarray, *, impl: str = "auto", block: int = 256
+        self, x: jnp.ndarray, *, impl: str = "auto", block: int = 256,
+        mesh=None,
     ) -> "GramMatvec":
-        """Matrix-free ``v ↦ K v`` over the fused kernel (K never built)."""
-        return GramMatvec(x, self.theta, self.lengthscale, impl, block)
+        """Matrix-free ``v ↦ K v`` over the fused kernel (K never built);
+        with ``mesh``, split by rows over its ``"solve"`` axis."""
+        return GramMatvec(x, self.theta, self.lengthscale, impl, block, mesh)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -48,37 +53,63 @@ class GramMatvec:
     shape, and never bakes ``x`` into its executable as a constant.
     ``v`` may be ``(n,)`` or column-stacked ``(n, r)``.
 
-    The Gram function (``kernels.ops.rbf_matvec``) is bound when the
-    matvec is made and kept in the aux data too: a jitted caller's cache
-    is keyed on the function it traced, so one that replaces the module's
-    function (a fault-injection test) is traced anew, not served a stale
-    executable.
+    With a ``mesh`` (a 1-D ``"solve"`` mesh, static aux data too) ``x``
+    and ``v`` are row-sharded over it: each device all-gathers the data
+    and the vector and applies its own row block against all columns
+    (``kernels.ops.rbf_matvec_rect`` under ``shard_map``), so a pass is
+    split over the chips.  A Pallas call is one custom call that GSPMD
+    cannot split; without the ``shard_map`` one chip would run the whole
+    pass.
+
+    The Gram function (``kernels.ops.rbf_matvec``, or ``rbf_matvec_rect``
+    on a mesh) is bound when the matvec is made and kept in the aux data
+    too: a jitted caller's cache is keyed on the function it traced, so
+    one that replaces the module's function (a fault-injection test) is
+    traced anew, not served a stale executable.
     """
 
-    __slots__ = ("x", "theta", "lengthscale", "impl", "block", "gram")
+    __slots__ = ("x", "theta", "lengthscale", "impl", "block", "gram", "mesh")
 
     def __init__(self, x, theta, lengthscale, impl: str = "auto",
-                 block: int = 256):
+                 block: int = 256, mesh=None):
         self.x, self.theta, self.lengthscale = x, theta, lengthscale
-        self.impl, self.block = impl, block
-        self.gram = kops.rbf_matvec
+        self.impl, self.block, self.mesh = impl, block, mesh
+        self.gram = kops.rbf_matvec if mesh is None else kops.rbf_matvec_rect
 
     def __call__(self, v: jnp.ndarray) -> jnp.ndarray:
-        return self.gram(
-            self.x, v, self.theta, self.lengthscale,
-            impl=self.impl, block=self.block,
-        )
+        if self.mesh is None:
+            return self.gram(
+                self.x, v, self.theta, self.lengthscale,
+                impl=self.impl, block=self.block,
+            )
+        return self._sharded(v)
+
+    def _sharded(self, v: jnp.ndarray) -> jnp.ndarray:
+        ax = sharded.SOLVE_AXIS
+        v_spec = P(ax) if v.ndim == 1 else P(ax, None)
+
+        def local(x_loc, v_loc, theta, lengthscale):
+            return self.gram(
+                x_loc, sharded.gather_x(x_loc), sharded.gather_v(v_loc),
+                theta, lengthscale, impl=self.impl, block=self.block,
+            )
+
+        return jax.shard_map(
+            local, mesh=self.mesh,
+            in_specs=(P(ax, None), v_spec, P(), P()), out_specs=v_spec,
+            check_vma=False,
+        )(self.x, v, jnp.asarray(self.theta), jnp.asarray(self.lengthscale))
 
     def tree_flatten(self):
         return (self.x, self.theta, self.lengthscale), (
-            self.impl, self.block, self.gram,
+            self.impl, self.block, self.gram, self.mesh,
         )
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
         mv = cls.__new__(cls)
         mv.x, mv.theta, mv.lengthscale = leaves
-        mv.impl, mv.block, mv.gram = aux
+        mv.impl, mv.block, mv.gram, mv.mesh = aux
         return mv
 
 

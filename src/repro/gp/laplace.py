@@ -29,6 +29,8 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import (
     KernelSystemOperator,
@@ -40,6 +42,7 @@ from repro.core import (
     randomized_nystrom,
 )
 from repro.core import pytree as pt
+from repro.core import sharded
 from repro.core.api import solve_jit
 from repro.core.operators import RBFKernelSystemOperator
 from repro.core.solvers import cg_jit
@@ -104,6 +107,23 @@ def _readout(f, y, a_vec, psi_prev, newton_tol, counts):
     return jnp.stack([v.astype(f.dtype) for v in row]), psi
 
 
+def place(mesh, x: jnp.ndarray, y: jnp.ndarray):
+    """``(x, y, moved_bytes)``: the data's rows split over ``mesh``'s
+    ``"solve"`` axis, as :func:`laplace_gpc` runs them there.  Arrays
+    already placed so are returned as they are; ``moved_bytes`` counts
+    what had to move."""
+    rows = NamedSharding(mesh, P(sharded.SOLVE_AXIS, None))
+    vec = NamedSharding(mesh, sharded.vector_spec())
+    out, moved = [], 0
+    for a, want in ((x, rows), (y, vec)):
+        if not (isinstance(a, jax.Array)
+                and a.sharding.is_equivalent_to(want, a.ndim)):
+            moved += a.nbytes
+            a = jax.device_put(a, want)
+        out.append(a)
+    return out[0], out[1], moved
+
+
 @dataclasses.dataclass
 class NewtonTrace:
     """Per-Newton-iteration record (mirrors the columns of paper Table 1)."""
@@ -146,6 +166,7 @@ def laplace_gpc(
     record_residuals: bool = False,
     k_dense: Optional[jnp.ndarray] = None,
     dense_matvec: bool = False,
+    mesh=None,
 ) -> LaplaceResult:
     """Find the Laplace mode f̂ of GP classification by Newton's method.
 
@@ -177,6 +198,17 @@ def laplace_gpc(
         formed once per hyperparameter setting); otherwise they use the
         fused matrix-free Gram matvec (O(n·d) memory, the TPU-scale path).
       dense_matvec: see above.
+      mesh: a 1-D ``"solve"`` mesh (:func:`repro.launch.mesh.make_solve_mesh`)
+        to split the fit over: ``x``, ``y`` and ``f`` are row-sharded on
+        it (:func:`place`; data already placed there is not moved), each
+        system is solved by the sharded def-CG (``solve(..., mesh=)``)
+        with its :class:`RecycleState` and warm start carried sharded from
+        system to system, and the driver's two Gram passes a system run
+        split over the chips (:class:`repro.gp.kernels.GramMatvec`).
+        Needs ``spec`` with ``precond="none"`` and the fused kernel (no
+        ``dense_matvec``); the sharded engine has no recovery ladder, so
+        every system reports rung 0.  ``None`` (the default) runs on one
+        device.
 
     The returned trace contains per-iteration log p(y|f), Ψ, solver
     iteration/matvec counts and cumulative wall time spent in the linear
@@ -184,14 +216,17 @@ def laplace_gpc(
     Figs 2–3 report.
 
     Spans (:mod:`repro.runtime.spans`): one ``laplace.fit`` (attrs
-    ``fit``, a process-wide id, ``systems`` and ``syncs``) holds one
+    ``fit``, a process-wide id, ``systems``, ``syncs`` and ``shards``, 1
+    or the mesh's size) holds, on a mesh, one ``laplace.place`` (attr
+    ``moved_bytes``, 0 when the data was placed already) and one
     ``laplace.system`` per Newton system, which holds
     ``laplace.newton_system``, ``laplace.solve`` and
     ``laplace.newton_step``, each one compiled program.  Every device
     read is one ``laplace.wait`` span: 2 per system (the solution, inside
     ``laplace.solve``, and one readout of log p, Ψ, the ΔΨ test and the
     solve's iterations, converged, matvecs and rung) and none per fit (the
-    returned Ψ and log p are the last readout's).
+    returned Ψ and log p are the last readout's), but one inside
+    ``laplace.place`` when data had to move.
     """
     n = x.shape[0]
     f = jnp.zeros(n, x.dtype)
@@ -203,11 +238,16 @@ def laplace_gpc(
                 "drive repro.core.solve directly for a custom M"
             )
         solver = "spec"
+    if mesh is not None and (solver != "spec" or dense_matvec):
+        raise ValueError(
+            "laplace_gpc(mesh=) solves through the sharded front door over "
+            "the fused Gram kernel: pass spec= and leave dense_matvec off"
+        )
     if (solver == "cholesky" or dense_matvec) and k_dense is None:
         k_dense = kernel.gram(x)
     if dense_matvec:
         k_mv = DenseMatvec(k_dense)
-    else:
+    elif mesh is None:
         k_mv = kernel.matvec_fn(x, impl=impl, block=block)
     if solver == "defcg" and recycle is None:
         recycle = RecycleManager(k=8, ell=12, tol=solver_tol, maxiter=solver_maxiter)
@@ -261,7 +301,7 @@ def laplace_gpc(
                 )
             res = solve_jit(
                 a_op, b, spec, solve_state, x0=x_prev, M=M,
-                record_residuals=record_residuals,
+                record_residuals=record_residuals, mesh=mesh,
             )
             solve_state = res.state
             return res.x, res.info, res.report.rung
@@ -287,7 +327,20 @@ def laplace_gpc(
     solve_time = 0.0
     converged = False
 
-    with spans.span("laplace.fit", fit=next(_fit_ids), systems=0) as fit:
+    shards = 1 if mesh is None else mesh.shape[sharded.SOLVE_AXIS]
+    with spans.span(
+        "laplace.fit", fit=next(_fit_ids), systems=0, shards=shards
+    ) as fit:
+        if mesh is not None:
+            with spans.span("laplace.place") as placed:
+                x, y, moved = place(mesh, x, y)
+                placed.attrs["moved_bytes"] = moved
+                if moved:
+                    spans.block((x, y), WAIT)
+                f = jnp.zeros(n, x.dtype, device=NamedSharding(
+                    mesh, sharded.vector_spec()
+                ))
+            k_mv = kernel.matvec_fn(x, impl=impl, block=block, mesh=mesh)
         for it in range(max_newton):
             with spans.span("laplace.system", syncs=0):
                 with spans.span("laplace.newton_system"):

@@ -46,7 +46,7 @@ from repro.kernels.cg_fused import (
 )
 from repro.kernels.blocks import F32
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.rbf_matvec import rbf_matvec_pallas, rbf_matvec_rect_pallas
+from repro.kernels.rbf_matvec import rbf_matvec_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 _NEG_INF = -1e30
@@ -104,32 +104,11 @@ def rbf_matvec(
     elif impl == "reference":
         out = ref.rbf_matvec(x, v2, theta, lengthscale)
     elif impl == "chunked":
-        out = _rbf_matvec_chunked(x / lengthscale, (theta**2) * v2, block)
+        xs = x / lengthscale
+        out = _rbf_matvec_chunked(xs, xs, (theta**2) * v2, block)
     else:
         raise ValueError(f"unknown impl={impl!r}")
     return out[:, 0] if squeeze else out
-
-
-def _rbf_matvec_chunked(xs: jnp.ndarray, vs: jnp.ndarray, block: int):
-    """Row-blocked Gram matvec: scan over i-blocks, full j per step.
-
-    O(block · n) score memory.  Same math as the Pallas kernel (pre-scaled
-    inputs), so dtype/rounding behaviour matches closely.
-    """
-    n, d = xs.shape
-    nb = max(1, block)
-    n_pad = ((n + nb - 1) // nb) * nb
-    xp = jnp.pad(xs, ((0, n_pad - n), (0, 0)))
-    sq_all = jnp.sum(xs * xs, axis=1)
-
-    def body(_, xi):
-        sq_i = jnp.sum(xi * xi, axis=1, keepdims=True)
-        cross = jnp.matmul(xi, xs.T, precision=F32)
-        d2 = jnp.maximum(sq_i + sq_all[None, :] - 2.0 * cross, 0.0)
-        return None, jnp.matmul(jnp.exp(-0.5 * d2), vs, precision=F32)
-
-    _, ys = jax.lax.scan(body, None, xp.reshape(-1, nb, d))
-    return ys.reshape(n_pad, vs.shape[1])[:n]
 
 
 def rbf_matvec_rect(
@@ -149,16 +128,17 @@ def rbf_matvec_rect(
     it against the full (all-gathered) column set — one call per shard,
     K never materialized.  ``x_rows`` is ``(m, d)``, ``x_cols`` ``(n, d)``,
     ``v`` ``(n,)`` or ``(n, r)``; output ``(m,)`` / ``(m, r)``.  The
-    square :func:`rbf_matvec` is the ``x_rows is x_cols`` special case.
+    square :func:`rbf_matvec` is the same kernel with the rows as the
+    columns.
     """
     squeeze = v.ndim == 1
     v2 = v[:, None] if squeeze else v
     impl = _resolve(impl, "rbf_matvec_rect", x_rows, x_cols, v)
     if impl in ("pallas", "interpret"):
-        out = rbf_matvec_rect_pallas(
+        out = rbf_matvec_pallas(
             x_rows / lengthscale,
-            x_cols / lengthscale,
             (theta**2) * v2,
+            x_cols / lengthscale,
             block_m=block,
             block_n=block,
             interpret=(impl == "interpret"),
@@ -166,7 +146,7 @@ def rbf_matvec_rect(
     elif impl == "reference":
         out = ref.rbf_matvec_rect(x_rows, x_cols, v2, theta, lengthscale)
     elif impl == "chunked":
-        out = _rbf_matvec_rect_chunked(
+        out = _rbf_matvec_chunked(
             x_rows / lengthscale, x_cols / lengthscale, (theta**2) * v2, block
         )
     else:
@@ -174,11 +154,14 @@ def rbf_matvec_rect(
     return out[:, 0] if squeeze else out
 
 
-def _rbf_matvec_rect_chunked(
+def _rbf_matvec_chunked(
     xr: jnp.ndarray, xc: jnp.ndarray, vs: jnp.ndarray, block: int
 ):
-    """Row-blocked rectangular Gram matvec — the chunked twin of
-    :func:`_rbf_matvec_chunked` with distinct row/column data."""
+    """Row-blocked Gram matvec ``K(xr, xc) @ vs``: a scan over row blocks,
+    all columns per step, O(block · n) score memory.  The same math as the
+    Pallas kernel (pre-scaled inputs), so dtype/rounding behaviour matches
+    closely; the square matvec passes the same data for rows and
+    columns."""
     m, d = xr.shape
     nb = max(1, block)
     m_pad = ((m + nb - 1) // nb) * nb

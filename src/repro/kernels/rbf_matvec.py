@@ -84,101 +84,51 @@ def _rbf_matvec_kernel(x_i_ref, x_j_ref, v_ref, o_ref, acc_ref):
     jax.jit, static_argnames=("block_m", "block_n", "interpret")
 )
 def rbf_matvec_pallas(
-    x_scaled: jnp.ndarray,
+    x_rows: jnp.ndarray,
     v_scaled: jnp.ndarray,
+    x_cols: jnp.ndarray | None = None,
     *,
     block_m: int = 256,
     block_n: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """``y = exp(−½‖x_i − x_j‖²) V`` over pre-scaled inputs.
+    """``y = exp(−½‖xr_i − xc_j‖²) V`` over pre-scaled inputs.
 
     Args:
-      x_scaled: (n, d) data, already divided by the lengthscale.
+      x_rows: (m, d) row data, already divided by the lengthscale.
       v_scaled: (n, r) right-hand sides, already scaled by θ².
+      x_cols: (n, d) column data, pre-scaled like ``x_rows``; ``None``
+        for the square Gram matvec, whose columns are its rows.  The
+        sharded operator passes its local row block as ``x_rows`` and the
+        all-gathered data as ``x_cols``: each shard forms the K-tiles of
+        (local rows × all columns) in VMEM, never in HBM.
       block_m/block_n: VMEM tile rows/cols; multiples of 128 on real TPUs.
       interpret: run the kernel body in Python on CPU (validation mode).
 
     Shapes are padded internally: j-padding is exact because padded V rows
-    are zero; padded i-rows are sliced off the output.
-    """
-    n, d = x_scaled.shape
-    _, r = v_scaled.shape
-
-    bm = min(block_m, max(round_up(n, 8), 8))
-    bn = min(block_n, max(round_up(n, 8), 8))
-    n_m = round_up(n, bm)
-    n_n = round_up(n, bn)
-    n_pad = max(n_m, n_n)
-    d_pad = round_up(d, 128)
-    r_pad = round_up(r, 8)
-
-    x_p = jnp.pad(x_scaled, ((0, n_pad - n), (0, d_pad - d)))
-    v_p = jnp.pad(v_scaled, ((0, n_pad - n), (0, r_pad - r)))
-
-    grid = (n_pad // bm, n_pad // bn)
-    out = pl.pallas_call(
-        _rbf_matvec_kernel,
-        grid=grid,
-        in_specs=[
-            block_spec((bm, d_pad), lambda i, j: (i, 0)),
-            block_spec((bn, d_pad), lambda i, j: (j, 0)),
-            block_spec((bn, r_pad), lambda i, j: (j, 0)),
-        ],
-        out_specs=block_spec((bm, r_pad), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, r_pad), v_scaled.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, r_pad), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="rbf_gram_matvec",
-    )(x_p, x_p, v_p)
-    return out[:n, :r]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_m", "block_n", "interpret")
-)
-def rbf_matvec_rect_pallas(
-    x_rows: jnp.ndarray,
-    x_cols: jnp.ndarray,
-    v_scaled: jnp.ndarray,
-    *,
-    block_m: int = 256,
-    block_n: int = 256,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Rectangular Gram matvec ``y = exp(−½‖xr_i − xc_j‖²) V``.
-
-    The sharded-operator building block: each shard holds a ROW block of
-    the data (``x_rows``, its local (m, d) slice) and applies the full
-    column set (``x_cols``, the all-gathered (n, d) data) to the gathered
-    right-hand sides — the K-tile for (local rows × all columns) is
-    formed and consumed in VMEM, never materialized.  The kernel body is
-    :func:`_rbf_matvec_kernel` unchanged (the square wrapper just passes
-    the same array for both row and column data); only the padding and
-    grid differ.
+    are zero; padded i-rows are sliced off the output.  The square case
+    pads ``x`` once, to one length for rows and columns, and hands the
+    same array to both.
     """
     m, d = x_rows.shape
-    n, _ = x_cols.shape
-    _, r = v_scaled.shape
+    n, r = v_scaled.shape
 
     bm = min(block_m, max(round_up(m, 8), 8))
     bn = min(block_n, max(round_up(n, 8), 8))
-    m_pad = round_up(m, bm)
-    n_pad = round_up(n, bn)
     d_pad = round_up(d, 128)
+    if x_cols is None:
+        m_pad = n_pad = max(round_up(n, bm), round_up(n, bn))
+        xr_p = xc_p = jnp.pad(x_rows, ((0, n_pad - n), (0, d_pad - d)))
+    else:
+        m_pad, n_pad = round_up(m, bm), round_up(n, bn)
+        xr_p = jnp.pad(x_rows, ((0, m_pad - m), (0, d_pad - d)))
+        xc_p = jnp.pad(x_cols, ((0, n_pad - n), (0, d_pad - d)))
     r_pad = round_up(r, 8)
-
-    xr_p = jnp.pad(x_rows, ((0, m_pad - m), (0, d_pad - d)))
-    xc_p = jnp.pad(x_cols, ((0, n_pad - n), (0, d_pad - d)))
     v_p = jnp.pad(v_scaled, ((0, n_pad - n), (0, r_pad - r)))
 
-    grid = (m_pad // bm, n_pad // bn)
     out = pl.pallas_call(
         _rbf_matvec_kernel,
-        grid=grid,
+        grid=(m_pad // bm, n_pad // bn),
         in_specs=[
             block_spec((bm, d_pad), lambda i, j: (i, 0)),
             block_spec((bn, d_pad), lambda i, j: (j, 0)),
@@ -191,7 +141,6 @@ def rbf_matvec_rect_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="rbf_gram_matvec_rect",
+        name="rbf_gram_matvec",
     )(xr_p, xc_p, v_p)
     return out[:m, :r]
-

@@ -180,7 +180,25 @@ def test_sharded_lsmr_damped_parity():
     )
     ref = solve(A, b, spec)
     got = solve(A, b, spec, mesh=make_solve_mesh(8))
-    np.testing.assert_allclose(got.x, ref.x, rtol=0, atol=1e-10)
+    # The two runs sum their reductions in different orders, and LSMR's
+    # coupled recurrences carry the difference to the stopping step, so
+    # x agrees to what the stopping rule leaves, not bitwise.  Both are
+    # stationary points of ‖Ax − b‖² + δ‖x‖² to within their normal
+    # residuals g = Aᵀ(b − Ax) − δx, and the normal matrix AᵀA + δI has
+    # no eigenvalue below σ_min(A)² + δ, so ‖x_got − x_ref‖ ≤ (‖g_got‖ +
+    # ‖g_ref‖) / (σ_min² + δ).  The 1e-12 covers the float64 rounding of
+    # evaluating g itself (‖A‖² ‖x‖ · n · eps).
+    a = np.asarray(A.mat)
+    bb = np.asarray(b)
+    damp = spec.lsq_shift
+
+    def normal_residual(x):
+        x = np.asarray(x)
+        return np.linalg.norm(a.T @ (bb - a @ x) - damp * x)
+
+    floor = np.linalg.svd(a, compute_uv=False)[-1] ** 2 + damp
+    bound = (normal_residual(got.x) + normal_residual(ref.x)) / floor
+    assert np.linalg.norm(np.asarray(got.x) - np.asarray(ref.x)) <= bound + 1e-12
     assert abs(int(got.info.iterations) - int(ref.info.iterations)) <= 5
     assert bool(got.info.converged) and bool(ref.info.converged)
 
@@ -241,7 +259,17 @@ def test_rbf_operator_sharded_parity():
     st = RecycleState.zeros(4, n, jnp.float64)
     ref = solve(A, b, spec, st)
     got = solve(A, b, spec, st, mesh=make_solve_mesh(8))
-    np.testing.assert_allclose(got.x, ref.x, rtol=0, atol=1e-10)
+    # The sharded solve sums each reduction per shard and then across
+    # shards, and its stopping test rides a one-step recurrence: x agrees
+    # to what the stopping rule leaves, not bitwise.  A = I + H½KH½ has no
+    # eigenvalue below 1, so two solutions differ by at most the sum of
+    # their true residuals; the 1e-12 covers the float64 rounding of
+    # evaluating those residuals (‖A‖ ‖x‖ · n · eps).  Each true residual
+    # is the solve's own stopping level: in float64 the recurrence that
+    # stopped it is within rounding of the true one, so 1% room.
+    true = [float(jnp.linalg.norm(b - A.matvec(r.x))) for r in (got, ref)]
+    assert max(true) <= 1.01 * spec.tol * float(jnp.linalg.norm(b))
+    assert float(jnp.linalg.norm(got.x - ref.x)) <= sum(true) + 1e-12
     assert abs(int(got.info.iterations) - int(ref.info.iterations)) <= 1
     assert abs(int(got.info.matvecs) - int(ref.info.matvecs)) <= 1
 

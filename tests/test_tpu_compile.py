@@ -13,20 +13,26 @@ every pytest worker collect the same tests while only the worker that
 runs this file loads it.  All such compiles live in this one file.
 
 Shapes are the chip smoke run's: n = 32768 points of d = 784 features,
-def-CG(k=8, ell=12), float32.
+def-CG(k=8, ell=12), float32; the four-chip fit's programs compile at
+n = 65536 over a described 2x2 mesh.
 """
 
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.gpc_mnist import CONFIG as GPC
+from repro.core import api as api_mod
 from repro.core.api import SolveSpec, solve, solve_pool_step
 from repro.core.operators import RBFKernelSystemOperator
 from repro.core.recycle import RecycleState
+from repro.gp import laplace
+from repro.gp.kernels import GramMatvec
 from repro.kernels import ops as kops
 
 N, D, K, ELL = 32768, GPC.d, GPC.k, GPC.ell
@@ -190,6 +196,39 @@ def test_f32_pool_step_compiles_for_v5e(one_chip, auto_is_pallas):
             jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
         )
     assert "rbf_gram_matvec" in hlo
+
+
+@pytest.fixture(scope="module")
+def solve_mesh(topo):
+    return Mesh(np.array(topo.devices), ("solve",))
+
+
+def test_sharded_newton_path_compiles_for_v5e_mesh(solve_mesh):
+    """The four-chip fit's programs at its shapes (n = 2^16 rows over a
+    2x2 v5e): the driver's Gram pass runs the one ``rbf_gram_matvec``
+    kernel on each chip's row block after an all-gather, and the warm
+    sharded def-CG solve reaches the same kernel."""
+    n = 2 * N
+    rows = NamedSharding(solve_mesh, P("solve", None))
+    vec = NamedSharding(solve_mesh, P("solve"))
+    basis = NamedSharding(solve_mesh, P(None, "solve"))
+    rep = NamedSharding(solve_mesh, P())
+    x, v = _f32(rows, n, D), _f32(vec, n)
+    k_mv = GramMatvec(x, 3.0, 3.0, "pallas", GPC.block, solve_mesh)
+    state = RecycleState(
+        W=_f32(basis, K, n), AW=_f32(basis, K, n), theta=_f32(rep, K),
+        systems_solved=jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+        drift=_f32(rep),
+    )
+    op = RBFKernelSystemOperator(x, v, 3.0, 3.0, GPC.block, "pallas")
+    with jax.enable_x64(False):
+        driver = laplace.newton_system.lower(v, v, k_mv).compile().as_text()
+        solve_hlo = api_mod.solve_jit.lower(
+            op, v, SPEC, state, x0=v, mesh=solve_mesh
+        ).compile().as_text()
+    for hlo in (driver, solve_hlo):
+        assert "rbf_gram_matvec" in hlo and "all-gather" in hlo
+    assert "all-reduce" in solve_hlo
 
 
 @pytest.mark.parametrize(
