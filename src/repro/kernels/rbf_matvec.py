@@ -7,11 +7,24 @@ one product with the kernel Gram matrix ``K(X, X)``.  Materializing ``K``
 
     tile (i, j):   S  = ‖xi‖² + ‖xj‖ᵀ² − 2·Xi Xjᵀ        (MXU: bm×d @ d×bn)
                    Kb = exp(−S/2)                          (VPU)
-                   Yi += Kb @ Vj                           (MXU: bm×bn @ bn×r)
+    r ≤ R_VPU:     Pc += Σ_chunks Kb[:, chunk] ⊙ Vᵀ[c, chunk]   (VPU, f32)
+                   Yi[:, c] = Σ_lanes Pc                    (last j only)
+    r > R_VPU:     Yi += Kb @ Vj                           (MXU: bm×bn @ bn×r)
 
 so HBM traffic is O(n·d + n·r) per pass instead of O(n²), and arithmetic
 intensity grows with the block size — the op becomes compute-bound, which
 is the right regime for the MXU (DESIGN.md §3).
+
+Which unit takes ``Kb·V`` is decided from ``r`` alone.  On the MXU a
+product with r ≤ 128 columns fills one 128-lane weight tile whatever r
+is, and at HIGHEST costs six bf16 passes of every Kb row: a third of the
+tile's MXU pushes at d = 256 and an eighth at d = 784, for 1/(d+1) of its
+flops at r = 1.  For narrow V the kernel instead keeps ``V`` with its n
+axis on lanes (``(r_pad, n)``) and, per column c, an f32 accumulator ``Pc``
+of lane-wide partial sums (bm × 128): each tile adds the exact f32
+products ``Kb[:, chunk] · Vᵀ[c, chunk]`` over its 128-lane chunks, and
+only the last j reduces ``Pc`` across lanes into column c of the output.
+The output block stays ``(bm, r_pad)`` on both paths.
 
 Parameter handling: the wrapper (ops.py) pre-scales ``X ← X/λ`` and
 ``V ← θ²·V``, so the kernel body is hyperparameter-free and never
@@ -36,26 +49,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.blocks import F32, block_spec, round_up
+from repro.runtime import spans
 
-# Both contractions run at full f32 precision (F32).  On a v5e at n = 2^15,
-# d = 784 (float64 reference on 256 rows) the kernel's relative error is
-# 3.7e-6 this way, 4.7e-3 with both at the default one-bf16-pass
-# precision and 5.4e-3 with only the distance term at HIGHEST: neither
-# cheaper choice is within a 1e-5 solve tolerance.  HIGHEST on the K·v
-# term is what limits the block to 256 (512 overflows VMEM).
+# The distance GEMM runs at full f32 precision (F32).  On a v5e at
+# n = 2^15, d = 784 (float64 reference on 256 rows) the kernel's relative
+# error is 3.7e-6 this way, 4.7e-3 with both contractions at the default
+# one-bf16-pass precision and 5.4e-3 with only the distance term at
+# HIGHEST: no cheaper choice is within a 1e-5 solve tolerance.  ``Kb·V``
+# for r ≤ R_VPU is an f32 multiply-add on the VPU, each product exact to
+# f32 rounding, so at least as exact as HIGHEST's six-pass bf16
+# emulation, which wider V keeps on the MXU.  The block stays 256: at
+# d = 784 a 512 block overflows VMEM on the MXU body and on the VPU body
+# at r = 8 (it fits at r = 1).
+
+# Widest V whose product runs on the VPU.  On a v5e the VPU body is the
+# faster at every r from 1 to 8, at d = 256 (n = 7291: 1.019 against
+# 1.436 ms a pass at r = 1, 1.105 against 1.435 at r = 8) and at d = 784
+# (n = 2^15: 63.01 against 70.69 ms, 64.41 against 70.64); 8 is the
+# recycled basis's k, so the AW refresh takes the VPU body too.
+R_VPU = 8
+LANES = 128
 
 
-def _rbf_matvec_kernel(x_i_ref, x_j_ref, v_ref, o_ref, acc_ref):
-    """One (bm × bn) tile of y += exp(−‖xi−xj‖²/2) @ v."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
+def _gram_tile(x_i_ref, x_j_ref):
+    """``Kb = exp(−‖xi−xj‖²/2)`` for one (bm × bn) tile, in f32."""
     xi = x_i_ref[...].astype(jnp.float32)  # (bm, d)
     xj = x_j_ref[...].astype(jnp.float32)  # (bn, d)
-    vj = v_ref[...].astype(jnp.float32)  # (bn, r)
 
     # Pairwise squared distances via one MXU matmul + rank-1 corrections.
     sq_i = jnp.sum(xi * xi, axis=1, keepdims=True)  # (bm, 1)
@@ -68,8 +87,19 @@ def _rbf_matvec_kernel(x_i_ref, x_j_ref, v_ref, o_ref, acc_ref):
         preferred_element_type=jnp.float32,
     )  # (bm, bn)
     dist2 = jnp.maximum(sq_i + sq_j - 2.0 * cross, 0.0)
-    kb = jnp.exp(-0.5 * dist2)
+    return jnp.exp(-0.5 * dist2)
 
+
+def _rbf_matvec_kernel(x_i_ref, x_j_ref, v_ref, o_ref, acc_ref):
+    """One (bm × bn) tile of y += exp(−‖xi−xj‖²/2) @ v on the MXU."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    kb = _gram_tile(x_i_ref, x_j_ref)
+    vj = v_ref[...].astype(jnp.float32)  # (bn, r)
     acc_ref[...] += jax.lax.dot_general(
         kb, vj, (((1,), (0,)), ((), ())),
         precision=F32, preferred_element_type=jnp.float32,
@@ -78,6 +108,35 @@ def _rbf_matvec_kernel(x_i_ref, x_j_ref, v_ref, o_ref, acc_ref):
     @pl.when(j == pl.num_programs(1) - 1)
     def _done():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _rbf_matvec_vpu_kernel(x_i_ref, x_j_ref, vt_ref, o_ref, acc_ref):
+    """The same tile with ``Kb·V`` on the VPU: ``vt_ref`` is (r_pad, bn),
+    V with its n axis on lanes and zero rows below r, and ``acc_ref``
+    (r, bm, lanes) holds each column's lane-wide partial sums."""
+    j = pl.program_id(1)
+    r, _, lanes = acc_ref.shape
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    kb = _gram_tile(x_i_ref, x_j_ref)  # (bm, bn)
+    vt = vt_ref[...].astype(jnp.float32)  # (r_pad, bn)
+    for c in range(r):
+        part = acc_ref[c]
+        for s in range(0, kb.shape[1], lanes):
+            part = part + kb[:, s:s + lanes] * vt[c:c + 1, s:s + lanes]
+        acc_ref[c] = part
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _done():
+        col = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+        out = jnp.zeros(o_ref.shape, jnp.float32)
+        for c in range(r):
+            y = jnp.sum(acc_ref[c], axis=1, keepdims=True)  # (bm, 1)
+            out = jnp.where(col == c, y, out)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -124,19 +183,32 @@ def rbf_matvec_pallas(
         xr_p = jnp.pad(x_rows, ((0, m_pad - m), (0, d_pad - d)))
         xc_p = jnp.pad(x_cols, ((0, n_pad - n), (0, d_pad - d)))
     r_pad = round_up(r, 8)
-    v_p = jnp.pad(v_scaled, ((0, n_pad - n), (0, r_pad - r)))
+    vpu = r <= R_VPU
+    spans.count("gram_kv_vpu" if vpu else "gram_kv_mxu")
+    if vpu:
+        # n on lanes: for r = 1 the transpose is a reshape.
+        lanes = LANES if bn % LANES == 0 else bn
+        kernel = _rbf_matvec_vpu_kernel
+        v_p = jnp.pad(v_scaled.T, ((0, r_pad - r), (0, n_pad - n)))
+        v_spec = block_spec((r_pad, bn), lambda i, j: (0, j))
+        acc = pltpu.VMEM((r, bm, lanes), jnp.float32)
+    else:
+        kernel = _rbf_matvec_kernel
+        v_p = jnp.pad(v_scaled, ((0, n_pad - n), (0, r_pad - r)))
+        v_spec = block_spec((bn, r_pad), lambda i, j: (j, 0))
+        acc = pltpu.VMEM((bm, r_pad), jnp.float32)
 
     out = pl.pallas_call(
-        _rbf_matvec_kernel,
+        kernel,
         grid=(m_pad // bm, n_pad // bn),
         in_specs=[
             block_spec((bm, d_pad), lambda i, j: (i, 0)),
             block_spec((bn, d_pad), lambda i, j: (j, 0)),
-            block_spec((bn, r_pad), lambda i, j: (j, 0)),
+            v_spec,
         ],
         out_specs=block_spec((bm, r_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, r_pad), v_scaled.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, r_pad), jnp.float32)],
+        scratch_shapes=[acc],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),

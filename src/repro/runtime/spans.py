@@ -14,9 +14,12 @@ One small facility for the host code of the program (``laplace_gpc``,
   the device (``jax.device_get`` and ``jax.block_until_ready``): each
   opens a span of the caller's name around the wait and adds one to the
   ``syncs`` attr of every span open around it.
-* A ``jax.monitoring`` listener, registered on the first span, adds
-  ``compiles`` and ``compile_s`` to the innermost open span whenever XLA
-  compiles: the span that recompiled says so.
+* :func:`count` adds to a counter attr of the innermost open span.  A
+  ``jax.monitoring`` listener, registered on the first span, counts
+  ``compiles`` and ``compile_s`` there whenever XLA compiles: the span
+  that recompiled says so.  Code that runs while a function is traced
+  counts the same way (the Gram kernel's ``gram_kv_vpu`` /
+  ``gram_kv_mxu``: which unit each traced call site's ``K·v`` took).
 * :func:`interval` appends a record whose start and end the caller
   measured, such as a served ticket's submit and redeem.
 
@@ -90,14 +93,18 @@ def _stack() -> List[Record]:
     return stack
 
 
-def _on_duration(event: str, duration: float, **_) -> None:
-    if event != COMPILE_EVENT:
-        return
+def count(attr: str, amount=1) -> None:
+    """Add ``amount`` to ``attr`` of the innermost open span, if any."""
     stack = getattr(_local, "stack", None)
     if stack:
         attrs = stack[-1].attrs
-        attrs["compiles"] = attrs.get("compiles", 0) + 1
-        attrs["compile_s"] = attrs.get("compile_s", 0.0) + duration
+        attrs[attr] = attrs.get(attr, 0) + amount
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    if event == COMPILE_EVENT:
+        count("compiles")
+        count("compile_s", duration)
 
 
 def _listen() -> None:

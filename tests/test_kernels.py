@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.analysis.trace_audit import iter_eqns
 from repro.kernels import ops, ref
+from repro.kernels.rbf_matvec import R_VPU
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
@@ -28,7 +30,9 @@ def _tol(dtype, scale=1.0):
 class TestRBFMatvec:
     @pytest.mark.parametrize("impl", ["interpret", "chunked"])
     @pytest.mark.parametrize(
-        "n,d,r", [(64, 3, 1), (200, 17, 4), (257, 784, 8), (8, 1, 1)]
+        "n,d,r",
+        [(64, 3, 1), (200, 17, 4), (257, 784, 8), (8, 1, 1),
+         (130, 7, R_VPU), (150, 5, R_VPU + 1)],
     )
     def test_matches_oracle(self, impl, n, d, r):
         rng = np.random.default_rng(n + d + r)
@@ -84,13 +88,68 @@ class TestRBFMatvec:
         )
         np.testing.assert_allclose(multi, singles, rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("r", [1, R_VPU, R_VPU + 1])
+    def test_lane_chunks_match_oracle(self, r):
+        # block 256: two 128-lane chunks per tile on the VPU body, two
+        # column tiles, and n not a multiple of the block.
+        rng = np.random.default_rng(r)
+        x = jnp.asarray(rng.standard_normal((300, 9)), F32)
+        v = jnp.asarray(rng.standard_normal((300, r)), F32)
+        want = np.asarray(ref.rbf_matvec(
+            x.astype(jnp.float64), v.astype(jnp.float64), 1.3, 2.1))
+        got = np.asarray(
+            ops.rbf_matvec(x, v, 1.3, 2.1, impl="interpret", block=256))
+        scale = max(1.0, np.abs(want).max())
+        np.testing.assert_allclose(got / scale, want / scale, **_tol(F32))
+
+    @pytest.mark.parametrize("r", [1, R_VPU + 1])
+    def test_vmapped_batch_matches_oracle(self, r):
+        # The serve pool step's form: one kernel call over a batch axis.
+        rng = np.random.default_rng(30 + r)
+        xb = jnp.asarray(rng.standard_normal((3, 90, 4)), F32)
+        vb = jnp.asarray(rng.standard_normal((3, 90, r)), F32)
+        got = np.asarray(jax.vmap(
+            lambda x, v: ops.rbf_matvec(x, v, 1.1, 1.7, impl="interpret",
+                                        block=32)
+        )(xb, vb))
+        for x, v, y in zip(xb, vb, got):
+            want = np.asarray(ref.rbf_matvec(
+                x.astype(jnp.float64), v.astype(jnp.float64), 1.1, 1.7))
+            scale = max(1.0, np.abs(want).max())
+            np.testing.assert_allclose(y / scale, want / scale, **_tol(F32))
+
+    @pytest.mark.parametrize("r,dots", [(1, 1), (R_VPU + 1, 2)])
+    def test_kv_product_runs_on_the_vpu_for_narrow_v(self, r, dots):
+        """At r ≤ R_VPU the kernel body holds one contraction, the
+        distance GEMM at HIGHEST; wider V adds the MXU ``Kb @ V``."""
+        x, v = jnp.ones((40, 3), F32), jnp.ones((40, r), F32)
+        jaxpr = jax.make_jaxpr(
+            lambda x, v: ops.rbf_matvec(x, v, 1.0, 1.0, impl="interpret",
+                                        block=32)
+        )(x, v)
+        calls = [e for e in iter_eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        assert "rbf_gram_matvec" in str(calls[0].params["name"])
+        found = [e for e in iter_eqns(calls[0].params["jaxpr"])
+                 if e.primitive.name == "dot_general"]
+        assert len(found) == dots
+        assert all(e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+                   for e in found)
+        cross = found[0]
+        assert [a.aval.shape for a in cross.invars] == [(32, 128), (32, 128)]
+
 
 class TestRBFMatvecRect:
     """Rectangular Gram matvec ``K(X_rows, X_cols) @ v`` — the sharded
     operator's per-shard primitive (DESIGN.md §5)."""
 
     @pytest.mark.parametrize("impl", ["interpret", "chunked"])
-    @pytest.mark.parametrize("m,n,d,r", [(48, 96, 3, 1), (33, 200, 11, 4)])
+    @pytest.mark.parametrize(
+        "m,n,d,r",
+        [(48, 96, 3, 1), (33, 200, 11, 4), (70, 150, 6, R_VPU),
+         (40, 130, 5, R_VPU + 1)],
+    )
     def test_matches_oracle(self, impl, m, n, d, r):
         rng = np.random.default_rng(m + n + d)
         xr = jnp.asarray(rng.standard_normal((m, d)), F32)

@@ -15,6 +15,8 @@ import pytest
 
 from repro.core import DenseMatrixOperator, SolveSpec
 from repro.gp import RBFKernel, laplace_gpc
+from repro.kernels import ops as kops
+from repro.kernels.rbf_matvec import R_VPU
 from repro.runtime import spans
 from repro.serve import SolveService
 
@@ -95,6 +97,22 @@ def test_compile_is_attributed_to_the_open_span():
     assert innermost.attrs["compiles"] >= 1
     assert innermost.attrs["compile_s"] > 0.0
     assert "compiles" not in span.attrs
+
+
+@pytest.mark.parametrize("r", [1, R_VPU + 1])
+def test_gram_kernel_counts_its_kv_unit_on_the_open_span(r):
+    """Tracing the Gram kernel leaves which unit took ``K·v`` on the
+    innermost open span; a shape no other test traces, so the kernel's
+    jit cache cannot skip the trace."""
+    x = jnp.ones((29, 3), jnp.float32)
+    v = jnp.ones((29, r), jnp.float32)
+    with spans.span("t.gram") as outer:
+        with spans.span("t.gram_inner") as inner:
+            kops.rbf_matvec(x, v, 1.0, 1.0, impl="interpret", block=32)
+    unit, other = (("gram_kv_vpu", "gram_kv_mxu") if r <= R_VPU
+                   else ("gram_kv_mxu", "gram_kv_vpu"))
+    assert inner.attrs[unit] == 1 and other not in inner.attrs
+    assert unit not in outer.attrs
 
 
 def test_spans_share_the_profiler_clock(tmp_path):

@@ -18,6 +18,7 @@ n = 65536 over a described 2x2 mesh.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,7 @@ from repro.core.recycle import RecycleState
 from repro.gp import laplace
 from repro.gp.kernels import GramMatvec
 from repro.kernels import ops as kops
+from repro.kernels.rbf_matvec import R_VPU
 
 N, D, K, ELL = 32768, GPC.d, GPC.k, GPC.ell
 
@@ -134,16 +136,34 @@ def test_kernel_compiles_for_v5e(one_chip, name, x64):
 
 
 def test_paper_config_block_is_largest_that_fits_vmem(one_chip):
-    """``GPC.block`` compiles; twice that overflows v5e VMEM."""
+    """``GPC.block`` compiles; twice that overflows v5e VMEM on the
+    wide-V MXU body, which is what caps the block (the VPU body at r = 1
+    would fit twice the block)."""
     x, v = _f32(one_chip, N, D), _f32(one_chip, N, 1)
+    wide = _f32(one_chip, N, 8 if R_VPU < 8 else R_VPU + 1)
 
     def mv(block):
         return lambda x, v: kops.rbf_matvec(x, v, 3.0, 3.0, impl="pallas",
                                             block=block)
 
     assert "tpu_custom_call" in _compile_text(mv(GPC.block), x, v)
+    assert "tpu_custom_call" in _compile_text(mv(GPC.block), x, wide)
     with pytest.raises(Exception, match="(?i)vmem"):
-        _compile_text(mv(2 * GPC.block), x, v)
+        _compile_text(mv(2 * GPC.block), x, wide)
+
+
+def _gram_out_shapes(hlo: str):
+    return re.findall(r"%rbf_gram_matvec[.\d]* = (f32\[[\d,]*\])", hlo)
+
+
+def test_narrow_gram_call_writes_one_pass_per_output(one_chip):
+    """At r = 1 (the VPU body) the kernel's output is the rank-2
+    ``f32[N,8]`` block column that ``bench/readers.passes`` counts as one
+    pass, not a rank-3 array of lane partial sums."""
+    fn, shapes = KERNELS["rbf_matvec"]
+    with jax.enable_x64(False):
+        hlo = _compile_text(fn, *(_f32(one_chip, *s) for s in shapes))
+    assert _gram_out_shapes(hlo) == [f"f32[{N},8]"]
 
 
 def _state_shapes(sharding, batch=()):
@@ -196,6 +216,8 @@ def test_f32_pool_step_compiles_for_v5e(one_chip, auto_is_pallas):
             jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
         )
     assert "rbf_gram_matvec" in hlo
+    # One rank-3 output under vmap: a pass per slot.
+    assert set(_gram_out_shapes(hlo)) == {f"f32[{slots},{N},8]"}
 
 
 @pytest.fixture(scope="module")
