@@ -16,7 +16,7 @@ jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402
 
-from repro.core import RecycleManager  # noqa: E402
+from repro.core import SolveSpec  # noqa: E402
 from repro.data import make_infinite_digits  # noqa: E402
 from repro.gp import RBFKernel, laplace_gpc  # noqa: E402
 
@@ -34,12 +34,17 @@ def main():
     kernel = RBFKernel(theta=args.theta, lengthscale=args.lengthscale)
     kd = kernel.gram(x)
 
+    specs = {
+        "cholesky": None,
+        "cg": SolveSpec(method="cg", tol=args.tol, maxiter=2000),
+        "defcg": SolveSpec(
+            method="defcg", k=8, ell=12, tol=args.tol, maxiter=2000
+        ),
+    }
     runs = {}
-    for solver in ("cholesky", "cg", "defcg"):
-        recycle = RecycleManager(k=8, ell=12) if solver == "defcg" else None
+    for solver, spec in specs.items():
         runs[solver] = laplace_gpc(
-            x, y, kernel, solver=solver, recycle=recycle,
-            solver_tol=args.tol, newton_tol=1.0,
+            x, y, kernel, spec=spec, newton_tol=1.0,
             k_dense=kd, dense_matvec=True,
         )
         r = runs[solver]

@@ -6,9 +6,9 @@ driven by one ``SolveSpec`` and carrying a ``RecycleState`` (see
 ``core/api.py``).  The spec's method axis covers both workload families:
 ``cg``/``defcg`` for SPD systems and ``lsmr``/``deflsmr`` for regularized
 least-squares (``core/lsmr.py``), all sharing the ``core/engine.py`` loop
-harness.  The older entry points (``cg``, ``defcg``, ``RecycleManager``,
-``recycled_solve_jit``) remain as host-side conveniences and
-compatibility shims over the same engine.
+harness.  ``cg``, ``defcg`` and ``lsmr`` are the solver bodies the front
+doors run, exported for tests and for callers that manage no recycled
+state.
 """
 
 from repro.core.api import (
@@ -56,14 +56,11 @@ from repro.core.preconditioners import (
 )
 from repro.core.recycle import (
     MAX_RECOVERY_RUNGS,
-    RecycleManager,
     RecycleState,
     SequenceResult,
     harmonic_ritz,
     harmonic_ritz_flat,
     random_orthonormal_basis,
-    recycled_solve_jit,
-    solve_sequence_jit,
 )
 from repro.core.solvers import (
     DEFAULT_WAW_JITTER,
@@ -121,14 +118,11 @@ __all__ = [
     "nystrom_preconditioner",
     "randomized_nystrom",
     "MAX_RECOVERY_RUNGS",
-    "RecycleManager",
     "RecycleState",
     "SequenceResult",
     "harmonic_ritz",
     "harmonic_ritz_flat",
     "random_orthonormal_basis",
-    "recycled_solve_jit",
-    "solve_sequence_jit",
     "DEFAULT_WAW_JITTER",
     "CGResult",
     "RecycleData",

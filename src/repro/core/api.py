@@ -363,6 +363,23 @@ def _check_m(spec: SolveSpec, M) -> None:
         )
 
 
+def _check_state(state: RecycleState, k: int, n: int, over: str) -> None:
+    """Reject a carried state whose basis does not fit ``spec.k`` and the
+    system's size, or whose ``AW`` is not one array shaped like ``W`` —
+    here, with the shapes named, not as an XLA shape error mid-solve."""
+    if state.W.ndim != 2 or state.W.shape != (k, n):
+        raise ValueError(
+            f"state.W has shape {state.W.shape}; spec(k={k}) over {over} "
+            f"needs ({k}, {n}) — state and spec must agree"
+        )
+    if getattr(state.AW, "shape", None) != state.W.shape:
+        raise ValueError(
+            "state.AW must be one array shaped like state.W "
+            f"{state.W.shape}, got "
+            f"{jax.tree_util.tree_map(jnp.shape, state.AW)}"
+        )
+
+
 # ---------------------------------------------------------------------------
 # solve — one system
 # ---------------------------------------------------------------------------
@@ -473,12 +490,7 @@ def solve(
         n = x_flat_t.shape[0]
         if state is None:
             state = RecycleState.zeros(spec.k, n, x_flat_t.dtype)
-        if state.W.ndim != 2 or state.W.shape != (spec.k, n):
-            raise ValueError(
-                f"state.W has shape {state.W.shape}; spec(k={spec.k}) over "
-                f"this system's domain needs ({spec.k}, {n}) — state and "
-                "spec must agree"
-            )
+        _check_state(state, spec.k, n, "this system's domain")
         x, info, w2, nw2, theta, rung = lsmr_mod._one_recycled_lsmr(
             A,
             b,
@@ -534,11 +546,7 @@ def solve(
     n = b_flat.shape[0]
     if state is None:
         state = RecycleState.zeros(spec.k, n, b_flat.dtype)
-    if state.W.ndim != 2 or state.W.shape != (spec.k, n):
-        raise ValueError(
-            f"state.W has shape {state.W.shape}; spec(k={spec.k}) over this "
-            f"system needs ({spec.k}, {n}) — state and spec must agree"
-        )
+    _check_state(state, spec.k, n, "this system")
 
     # Per-system semantics (refresh policy, accounting, strategy
     # transition, recovery ladder) are shared with solve_sequence's scan

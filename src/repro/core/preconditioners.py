@@ -8,8 +8,10 @@ latter can seed the former.  This module provides:
 * :func:`randomized_nystrom` — a randomized Nyström low-rank eigensketch of
   a matrix-free SPD operator (sketch → QR → Rayleigh–Ritz), usable both as
   (a) a preconditioner ``M⁻¹ = U (Λ+σ)⁻¹ Uᵀ + (I − UUᵀ)/σ_scale`` and
-  (b) an initial deflation basis for :class:`repro.core.recycle.RecycleManager`
-      (``seed="nystrom"`` — the paper's 'missing link' initialization).
+  (b) an initial deflation basis: its stacked ``U`` (as flat ``(k, n)``
+      rows) with a zero ``AW`` is a :class:`repro.core.RecycleState` that
+      ``repro.core.solve`` refreshes exactly before the first solve — the
+      paper's 'missing link' initialization.
 """
 
 from __future__ import annotations

@@ -40,7 +40,6 @@ Two implementations share the same math:
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -50,17 +49,14 @@ from repro.core import operators as ops_mod
 from repro.core import pytree as pt
 from repro.core.solvers import (
     DEFAULT_WAW_JITTER,
-    CGResult,
     SolveInfo,
     _flat_operator,
     defcg,
-    defcg_jit,
 )
 from repro.core.strategies import (
     HarmonicRitz,
     RecycleStrategy,
     _select_positive_ritz,
-    extract_next_basis_core,
     harmonic_ritz_flat_core,
 )
 
@@ -70,14 +66,13 @@ Pytree = Any
 @jax.tree_util.register_pytree_with_keys_class
 @dataclasses.dataclass
 class RecycleState:
-    """First-class recycled-subspace state — the carry of every solve path.
+    """First-class recycled-subspace state — the carry of every solve path
+    (``solve``, ``solve_sequence``, ``solve_batch``, ``hf_step``).
 
-    Replaces the bare ``(W, AW)`` pairs previously threaded through
-    ``RecycleManager``, ``recycled_solve_jit``, ``hf_step`` and
-    ``solve_sequence``'s scan carry.  A registered pytree node (with
-    stable key names, so it round-trips through ``repro.checkpoint``
-    by leaf path), it vmaps over a leading tenant axis (``solve_batch``)
-    and shards like the solution vector under pjit.
+    A registered pytree node (with stable key names, so it round-trips
+    through ``repro.checkpoint`` by leaf path), it vmaps over a leading
+    tenant axis (``solve_batch``) and shards like the solution vector
+    under pjit.
 
     Attributes:
       W: flat ``(k, n)`` recycled basis rows.  Zero rows are empty slots
@@ -212,11 +207,6 @@ def harmonic_ritz(
     return W, AW, theta
 
 
-harmonic_ritz_jit = jax.jit(
-    harmonic_ritz, static_argnames=("k", "select", "jitter")
-)
-
-
 def harmonic_ritz_flat(
     Z: jnp.ndarray,
     AZ: jnp.ndarray,
@@ -251,27 +241,6 @@ def harmonic_ritz_flat(
     """
     W, AW, theta, _ = harmonic_ritz_flat_core(
         Z, AZ, k, valid=valid, select=select, jitter=jitter
-    )
-    return W, AW, theta
-
-
-def _extract_next_basis(
-    w_flat: Optional[jnp.ndarray],
-    aw_flat: Optional[jnp.ndarray],
-    p_flat: jnp.ndarray,
-    ap_flat: jnp.ndarray,
-    stored,
-    k: int,
-    *,
-    select: str = "largest",
-    jitter: float = 1e-10,
-):
-    """One cross-system extraction on the flat engine (3-tuple wrapper
-    over :func:`repro.core.strategies.extract_next_basis_core` — the
-    strategy layer's shared masked extraction)."""
-    W, AW, theta, _ = extract_next_basis_core(
-        w_flat, aw_flat, p_flat, ap_flat, stored, k,
-        select=select, jitter=jitter,
     )
     return W, AW, theta
 
@@ -802,409 +771,6 @@ def solve_sequence(
         x=xs_out, info=infos, theta=thetas, W=w_fin, AW=aw_fin,
         drift=drift_fin, rung=rungs,
     )
-
-
-solve_sequence_jit = jax.jit(
-    solve_sequence,
-    static_argnames=(
-        "k",
-        "ell",
-        "make_operator",
-        "make_preconditioner",
-        "tol",
-        "atol",
-        "maxiter",
-        "select",
-        "waw_jitter",
-        "refresh_aw",
-        "carry_x",
-        "strategy",
-        "divergence_fallback",
-        "batch_axis",
-        "recovery_rungs",
-        "recovery_shift",
-        "stagnation_window",
-    ),
-)
-
-
-def _apply_basis_maybe_jit(A, W):
-    """One multi-RHS ``A @ W`` — jitted when A is a pytree node
-    (stable-closure operators hit the jit cache), eager otherwise."""
-    try:
-        return _apply_basis_jitted(A, W)
-    except TypeError:  # A is a bare callable, not a registered pytree node
-        return ops_mod.apply_to_basis(A, W)
-
-
-@jax.jit
-def _apply_basis_jitted(A, W):
-    return ops_mod.apply_to_basis(A, W)
-
-
-@dataclasses.dataclass
-class RecycleManager:
-    """Carries the recycled subspace across a *sequence* of SPD systems.
-
-    This object is the host-driven convenience wrapper over the sequence
-    engine: call :meth:`solve` once per system ``A⁽ⁱ⁾ x = b⁽ⁱ⁾``; it runs
-    ``def-CG(k, ell)`` with the current recycled basis (plain CG +
-    recording for the first system), then refreshes the basis by the flat
-    masked harmonic-Ritz extraction — the stored count stays a device
-    scalar (no host round-trip), and the ``AW`` refresh is one multi-RHS
-    operator application.  Fully-jitted outer loops should scan
-    :func:`solve_sequence` instead (one XLA computation, zero host
-    involvement between systems); the manager adds host-side resilience
-    (breakdown fallback) on the same primitives.
-
-    ``refresh_aw`` controls how ``A⁽ⁱ⁺¹⁾W`` is obtained:
-
-    * ``"exact"`` — recompute with one multi-RHS pass (k matvecs of
-      operator work — the O(k n²) overhead the paper accounts for in
-      §2.2).  Deflation identities hold exactly.
-    * ``"stale"`` — reuse ``A⁽ⁱ⁾W = AZ·U`` from the extraction (zero
-      matvecs; this matches the paper's ``O(n²(ℓ+1)k)`` cost accounting
-      for obtaining *both* W and AW from stored quantities).  The
-      deflation projector is then approximate, and with operator drift
-      the error compounds through the direction recurrence: ``Wᵀr = 0``
-      is no longer maintained, the CG step scalars lose their line-search
-      property, and the solve can *diverge* outright (observed; the
-      extreme form of the Fig. 2 stagnation).  The breakdown fallback
-      below catches exactly this — it re-solves clean and, since the
-      accounting fix, reports the true total cost including the failed
-      attempt.  Stale mode is exact (and safe) when the operator is
-      unchanged between systems — the multiple-RHS setting.  The
-      ``strategy`` field generalizes this switch:
-      :class:`repro.core.strategies.WindowedRecombine` is the guarded
-      stale mode (drift measured for free, refresh only when needed).
-
-    ``reuse_aw=True`` on a call additionally declares the operator
-    unchanged since the previous solve (multiple RHS against one matrix).
-
-    ``strategy`` selects the :class:`repro.core.strategies.RecycleStrategy`
-    owning the refresh decision (its host-side
-    ``manager_wants_refresh`` mirror) and the end-of-solve transition;
-    the strategy's drift measurement is carried in ``state.drift``.
-
-    The manager carries a :class:`RecycleState` (flat ``(k, n)`` device
-    arrays): it shards like the solution vector, persists on-device across
-    systems, and is checkpointable (``repro.checkpoint`` saves it with the
-    train state).  ``W``/``AW``/``theta`` remain readable as properties.
-    """
-
-    k: int
-    ell: int
-    select: str = "largest"
-    tol: float = 1e-5
-    maxiter: int = 1000
-    waw_jitter: float = DEFAULT_WAW_JITTER
-    refresh_aw: str = "exact"  # "exact" | "stale" (see class docstring)
-    strategy: RecycleStrategy = HarmonicRitz()
-    use_jit: bool = True
-    state: Optional[RecycleState] = None
-    systems_solved: int = 0
-    _has_aw: bool = False  # state.AW holds real A-products (not placeholder)
-
-    @property
-    def W(self) -> Optional[jnp.ndarray]:
-        """Flat ``(m, n)`` recycled basis rows, or None before bootstrap."""
-        return None if self.state is None else self.state.W
-
-    @property
-    def AW(self) -> Optional[jnp.ndarray]:
-        """A-products of ``W`` (None when seeded without them)."""
-        if self.state is None or not self._has_aw:
-            return None
-        return self.state.AW
-
-    @property
-    def theta(self) -> Optional[jnp.ndarray]:
-        return None if self.state is None else self.state.theta
-
-    def seed(self, W: Pytree, AW: Optional[Pytree] = None) -> None:
-        """Seed the recycle space a priori (e.g. Nyström vectors — the
-        paper's §1.1 'guessed projective space as first initialization').
-
-        ``W`` is a stacked basis (pytree or flat ``(m, n)``) of at most
-        ``self.k`` vectors; shape/k-consistency is validated HERE, with a
-        host-side error, instead of surfacing as an XLA shape failure in
-        the middle of the next solve.
-        """
-        w_flat = pt.ravel_basis(W)
-        m = w_flat.shape[0]
-        if not 1 <= m <= self.k:
-            raise ValueError(
-                f"seed basis has {m} vectors; RecycleManager(k={self.k}) "
-                f"can carry between 1 and {self.k}"
-            )
-        aw_flat = None
-        if AW is not None:
-            if jax.tree_util.tree_structure(
-                AW
-            ) != jax.tree_util.tree_structure(W):
-                raise ValueError(
-                    "seed AW must have the same pytree structure as W, got "
-                    f"{jax.tree_util.tree_structure(AW)} vs "
-                    f"{jax.tree_util.tree_structure(W)}"
-                )
-            aw_flat = pt.ravel_basis(AW)
-            if aw_flat.shape != w_flat.shape:
-                raise ValueError(
-                    f"seed AW shape {aw_flat.shape} does not match W "
-                    f"shape {w_flat.shape}"
-                )
-        self.state = RecycleState(
-            W=w_flat,
-            AW=jnp.zeros_like(w_flat) if aw_flat is None else aw_flat,
-            theta=jnp.zeros((m,), w_flat.dtype),
-            systems_solved=jnp.int32(self.systems_solved),
-            drift=jnp.zeros((), w_flat.dtype),
-        )
-        self._has_aw = aw_flat is not None
-
-    def solve(
-        self,
-        A,
-        b: Pytree,
-        x0: Optional[Pytree] = None,
-        *,
-        reuse_aw: bool = False,
-        tol: Optional[float] = None,
-        maxiter: Optional[int] = None,
-        record_residuals: bool = False,
-        M=None,
-    ) -> CGResult:
-        tol = self.tol if tol is None else tol
-        maxiter = self.maxiter if maxiter is None else maxiter
-        if self.strategy.needs_preconditioner and M is None:
-            # Without M the M-geometry transition would silently degrade
-            # to the Euclidean extraction — the SolveSpec path rejects
-            # this combination too (spec validation).
-            raise ValueError(
-                f"strategy={type(self.strategy).__name__} extracts in the "
-                "preconditioner's geometry — pass M to every solve()"
-            )
-
-        w_flat = self.state.W if self.state is not None else None
-        aw_flat = self.AW  # None when seeded without A-products
-        # A basis with no A-products at all (seed() without AW) must be
-        # refreshed even under reuse_aw — there is nothing to reuse.
-        # Otherwise the refresh decision belongs to the strategy (exact
-        # policy / drift guard / pure stale) — the host-side mirror of
-        # ``strategy.prepare`` on the device paths.
-        drift = (
-            self.state.drift if self.state is not None else jnp.float32(0.0)
-        )
-        needs_fresh = w_flat is not None and (
-            aw_flat is None
-            or (
-                not reuse_aw
-                and self.strategy.manager_wants_refresh(
-                    self.refresh_aw, drift, tol
-                )
-            )
-        )
-        if needs_fresh:
-            _, unravel = pt.ravel_vector(b)
-            basis = pt.unravel_basis(w_flat, unravel)
-            aw = (
-                _apply_basis_maybe_jit(A, basis)
-                if self.use_jit
-                else ops_mod.apply_to_basis(A, basis)
-            )
-            aw_flat = pt.ravel_basis(aw)
-
-        solve_fn = defcg_jit if self.use_jit else defcg
-        exact_aw = needs_fresh or reuse_aw or w_flat is None
-        result = solve_fn(
-            A,
-            b,
-            x0,
-            W=w_flat,
-            AW=aw_flat,
-            ell=self.ell,
-            tol=tol,
-            maxiter=maxiter,
-            record_residuals=record_residuals,
-            waw_jitter=self.waw_jitter,
-            exact_aw=exact_aw,
-            flat_recycle=True,  # _refresh consumes (P, AP) flat
-            M=M,
-            # A stale solve gets the strategy's in-solve drift guard —
-            # the same layer-2 protection the device paths arm through
-            # strategy.prepare (its k-matvec refresh is charged by defcg).
-            stale_guard=(
-                None if exact_aw else self.strategy.in_solve_guard(tol)
-            ),
-        )
-        if result.recycle is not None and result.recycle.aw_used is not None:
-            # The in-solve guard may have refreshed — extract from what
-            # the solve actually deflated with.
-            aw_flat = result.recycle.aw_used
-        # Charge what the refresh actually computed: a seeded basis may
-        # hold fewer than self.k vectors.
-        refresh_cost = w_flat.shape[0] if needs_fresh else 0
-
-        if w_flat is not None and (
-            bool(result.info.breakdown) or not bool(result.info.converged)
-        ):
-            # Resilience: a stale/ill-conditioned basis can poison the
-            # conjugacy recurrences.  Drop it and re-solve clean — the
-            # sequence continues with a freshly bootstrapped space.  The
-            # failed attempt's matvecs (and the refresh spent on the
-            # discarded basis) were still paid — fold them into the
-            # reported total rather than silently dropping them.
-            failed_matvecs = result.info.matvecs
-            self.state = None
-            self._has_aw = False
-            w_flat = aw_flat = None
-            result = solve_fn(
-                A, b, x0,
-                ell=self.ell, tol=tol, maxiter=maxiter,
-                record_residuals=record_residuals,
-                flat_recycle=True,
-                M=M,
-            )
-            result = result._replace(
-                info=result.info._replace(
-                    matvecs=result.info.matvecs
-                    + failed_matvecs
-                    + refresh_cost
-                )
-            )
-        elif refresh_cost:
-            result = result._replace(
-                info=result.info._replace(
-                    matvecs=result.info.matvecs + refresh_cost
-                )
-            )
-        self.systems_solved += 1
-        self._refresh(result, w_flat, aw_flat, b=b, M=M)
-        return result
-
-    # -- internal ----------------------------------------------------------
-    def _refresh(
-        self,
-        result: CGResult,
-        w_flat: Optional[jnp.ndarray],
-        aw_flat: Optional[jnp.ndarray],
-        *,
-        b: Pytree,
-        M=None,
-    ) -> None:
-        rec = result.recycle
-        if rec is None:
-            return
-        if int(rec.stored) == 0:
-            # Nothing recorded (0-iteration solve: x0 was already exact) —
-            # keep the current basis as-is.  In particular a None state
-            # must stay None, not become a phantom zero basis that every
-            # later solve "refreshes" for k wasted matvecs.  This scalar
-            # read costs nothing extra: solve() already synced on
-            # result.info.converged, so the value is sitting on the host
-            # side of a completed computation — unlike the old path, it
-            # gates no shapes and triggers no per-count recompiles.
-            return
-        # Strategy-owned transition on the flat masked extraction: the
-        # dynamic stored count feeds the jitted extraction as a device
-        # scalar (the pre-flat-engine path static-sliced on it,
-        # recompiling for every distinct count).
-        P, AP = rec.P, rec.AP  # already flat (flat_recycle=True)
-        k = min(self.k, P.shape[0] + (0 if w_flat is None else w_flat.shape[0]))
-        if self.strategy.needs_preconditioner and M is not None:
-            # M-geometry needs the flat M⁻¹ apply — a per-call closure,
-            # so this path runs eagerly (the front doors jit it whole).
-            _, unravel = pt.ravel_vector(b)
-            W_new, AW_new, theta, drift = self.strategy.transition(
-                w_flat, aw_flat, rec, k=k, select=self.select,
-                m_apply=_flat_operator(M, unravel),
-            )
-        elif self.use_jit:
-            W_new, AW_new, theta, drift = _strategy_transition_jit(
-                self.strategy, w_flat, aw_flat, rec, k, self.select
-            )
-        else:
-            W_new, AW_new, theta, drift = self.strategy.transition(
-                w_flat, aw_flat, rec, k=k, select=self.select
-            )
-        self.state = RecycleState(
-            W=W_new,
-            AW=AW_new,
-            theta=theta,
-            systems_solved=jnp.int32(self.systems_solved),
-            drift=drift,
-        )
-        self._has_aw = True
-
-
-_extract_next_basis_jit = jax.jit(
-    _extract_next_basis, static_argnames=("k", "select", "jitter")
-)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("strategy", "k", "select")
-)
-def _strategy_transition_jit(strategy, w_flat, aw_flat, window, k, select):
-    """Jitted strategy transition for the host-driven manager (strategies
-    are hashable static config; the window rides in as a traced pytree)."""
-    return strategy.transition(w_flat, aw_flat, window, k=k, select=select)
-
-
-def recycled_solve_jit(
-    A,
-    b: Pytree,
-    x0: Pytree,
-    W: Pytree,
-    *,
-    k: int,
-    ell: int,
-    tol: float,
-    maxiter: int,
-    select: str = "largest",
-) -> Tuple[Pytree, Pytree, CGResult]:
-    """Single-shot, fully traceable solve+extract for jitted outer loops.
-
-    One step of the sequence engine for callers that carry ``W`` in their
-    own state (the Hessian-free optimizer): one multi-RHS ``AW`` refresh,
-    a flat def-CG solve, and the masked flat extraction.  The recording
-    window no longer needs a ``min_iters`` floor — a partially filled
-    window extracts through the validity mask, so early-converging solves
-    stop early instead of burning ``ell`` matvecs to fill buffers.
-
-    Callers bootstrap with a random orthonormal basis, which is a valid
-    (merely unhelpful) deflation space.  Returns ``(W_next, x, result)``.
-    """
-    AW = ops_mod.apply_to_basis(A, W)
-    result = defcg(
-        A,
-        b,
-        x0,
-        W=W,
-        AW=AW,
-        ell=ell,
-        tol=tol,
-        maxiter=maxiter,
-        flat_recycle=True,
-    )
-    _, unravel = pt.ravel_vector(b)
-    w_flat = pt.ravel_basis(W)
-    aw_flat = pt.ravel_basis(AW)
-    W_next, _, _ = _extract_next_basis(
-        w_flat,
-        aw_flat,
-        result.recycle.P,
-        result.recycle.AP,
-        result.recycle.stored,
-        k,
-        select=select,
-    )
-    result = result._replace(
-        info=result.info._replace(
-            matvecs=result.info.matvecs + pt.basis_size(W)
-        )
-    )
-    return pt.unravel_basis(W_next, unravel), result.x, result
 
 
 def random_orthonormal_basis(key, template: Pytree, k: int) -> Pytree:

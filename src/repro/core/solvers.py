@@ -668,60 +668,3 @@ def defcg(
 def cholesky_solve(mat: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Exact SPD solve via Cholesky — the paper's cubic-cost baseline."""
     return cho_solve(cho_factor(mat), b)
-
-
-# ---------------------------------------------------------------------------
-# Jitted entry points
-# ---------------------------------------------------------------------------
-#
-# Solver arguments that select code paths are static; vectors/bases/operators
-# are traced.  Operators registered as pytree nodes keep their matvec
-# closures in aux_data — reusing the *same* closure object across calls (as
-# the Laplace loop and RecycleManager do) makes these hit the jit cache, so
-# a Newton sequence compiles each solver variant exactly once.
-
-# ``M`` is a TRACED argument of the jitted entry points: preconditioners
-# (``repro.core.preconditioners``) are registered pytree nodes whose data
-# (diag, sketch basis) are children, so a Newton loop that rebuilds its
-# Jacobi/Nyström preconditioner every system hits the jit cache instead of
-# recompiling.  A bare closure is not traceable data; ``cg_jit`` keeps the
-# pre-redesign behavior for those by routing them through a static-M jit
-# (cached by closure identity — stable closures still cache-hit).
-
-_cg_jit_traced_m = jax.jit(
-    cg,
-    static_argnames=("tol", "atol", "maxiter", "record_residuals", "stagnation_window"),
-)
-_cg_jit_static_m = jax.jit(
-    cg,
-    static_argnames=("tol", "atol", "maxiter", "M", "record_residuals", "stagnation_window"),
-)
-
-
-def cg_jit(*args, **kwargs):
-    """Jitted :func:`cg`.  ``M`` may be None, a registered pytree node
-    (traced — rebuild freely, one compilation), or a bare callable
-    (static — falls back to hashing by identity, as before the
-    SolveSpec redesign)."""
-    M = kwargs.get("M")
-    if M is not None and jax.tree_util.all_leaves([M]):
-        return _cg_jit_static_m(*args, **kwargs)
-    return _cg_jit_traced_m(*args, **kwargs)
-
-defcg_jit = jax.jit(
-    defcg,
-    static_argnames=(
-        "ell",
-        "tol",
-        "atol",
-        "maxiter",
-        "min_iters",
-        "record_residuals",
-        "waw_jitter",
-        "exact_aw",
-        "flat_recycle",
-        "batch_axis",
-        "stale_guard",
-        "stagnation_window",
-    ),
-)

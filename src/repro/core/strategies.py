@@ -359,8 +359,6 @@ class RecycleStrategy:
       ``(W', AW', theta, drift)``.  ``drift`` is the strategy's own
       carried scalar (stored in ``RecycleState.drift``); strategies that
       do not guard return 0.
-    * :meth:`manager_wants_refresh` — the host-side mirror of
-      :meth:`prepare`'s refresh decision, for :class:`RecycleManager`.
 
     Instances are frozen, hashable, and leaf-less pytree nodes — valid
     both as jit-static config (inside ``SolveSpec``) and inside traced
@@ -393,16 +391,6 @@ class RecycleStrategy:
         m_apply: Optional[FlatApply] = None,
     ):
         raise NotImplementedError
-
-    def manager_wants_refresh(self, refresh_aw: str, drift, tol: float) -> bool:
-        raise NotImplementedError
-
-    def in_solve_guard(self, tol: float):
-        """Static ``defcg(stale_guard=…)`` threshold, or None (no
-        in-solve guard) — lets host-driven callers arm the same layer-2
-        protection the device paths get from :meth:`prepare`."""
-        del tol
-        return None
 
     @property
     def needs_preconditioner(self) -> bool:
@@ -447,10 +435,6 @@ class HarmonicRitz(RecycleStrategy):
             select=select, jitter=jitter,
         )
         return W, AW, theta, _zero_drift(W)
-
-    def manager_wants_refresh(self, refresh_aw, drift, tol):
-        del drift, tol
-        return refresh_aw == "exact"
 
 
 @_register_strategy
@@ -539,13 +523,6 @@ class WindowedRecombine(RecycleStrategy):
         )
         return W, AW, theta, fasym.astype(W.dtype)
 
-    def manager_wants_refresh(self, refresh_aw, drift, tol):
-        del refresh_aw
-        # The host-side mirror of prepare(): same tol-scaled threshold,
-        # same dtype noise floor.
-        d = jnp.asarray(drift)
-        return bool(d > _drift_threshold(self.guard, tol, d.dtype))
-
 
 @_register_strategy
 @dataclasses.dataclass(frozen=True)
@@ -578,10 +555,6 @@ class MGeometryHarmonic(RecycleStrategy):
             select=select, jitter=jitter, m_apply=m_apply,
         )
         return W, AW, theta, _zero_drift(W)
-
-    def manager_wants_refresh(self, refresh_aw, drift, tol):
-        del refresh_aw, drift, tol
-        return True
 
     @property
     def needs_preconditioner(self) -> bool:

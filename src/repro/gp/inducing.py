@@ -53,7 +53,7 @@ def subset_gpc(
     t0 = time.perf_counter()
     sub = laplace_gpc(
         xm, ym, kernel,
-        solver="cholesky", newton_tol=newton_tol, max_newton=max_newton,
+        spec=None, newton_tol=newton_tol, max_newton=max_newton,
     )
 
     # Induce the full latent vector through the conditional mean.

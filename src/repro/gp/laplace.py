@@ -6,16 +6,17 @@ iteration solves the SPD system (paper Eq. 9–10)
 
     A⁽ⁱ⁾ = I + H½ K H½,       b⁽ⁱ⁾ = H½ K (H f + ∇ log p(y|f)),
 
-whose eigenvalues lie in [1, n·max(K)/4].  The solver is pluggable —
-``cholesky`` (exact, the paper's cubic baseline), ``cg``, or ``defcg``
-with a :class:`repro.core.RecycleManager` carrying the deflation basis
-across Newton iterations (the paper's contribution).  Since the operator
-changes every Newton step (H½ moves with f), the manager recomputes
-``A⁽ⁱ⁾W`` each iteration — via ``basis_matvec`` of
-``RBFKernelSystemOperator`` (or of ``KernelSystemOperator`` over a dense
-K) this is ONE fused multi-RHS Gram pass (each K-tile formed once for all k
-recycled vectors), not k sequential matvecs; both the matrix-free kernel
-matvec and the dense ``K @ V`` path batch natively.
+whose eigenvalues lie in [1, n·max(K)/4].  Each system is solved either
+exactly by a dense Cholesky factorization (``spec=None``, the paper's
+cubic baseline) or through the front door ``repro.core.solve`` per a
+:class:`repro.core.SolveSpec` — ``method="defcg"`` carries the recycled
+deflation basis across Newton iterations in a ``RecycleState`` (the
+paper's contribution).  Since the operator changes every Newton step (H½
+moves with f), an exact refresh recomputes ``A⁽ⁱ⁾W`` each iteration — via
+``basis_matvec`` of ``RBFKernelSystemOperator`` (or of
+``KernelSystemOperator`` over a dense K) this is ONE fused multi-RHS Gram
+pass (each K-tile formed once for all k recycled vectors), not k
+sequential matvecs.
 
 The logistic likelihood p(y_i|f_i) = σ(y_i f_i) with y ∈ {−1, +1}.
 """
@@ -34,7 +35,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import (
     KernelSystemOperator,
-    RecycleManager,
     SolveSpec,
     cholesky_solve,
     jacobi,
@@ -45,7 +45,6 @@ from repro.core import pytree as pt
 from repro.core import sharded
 from repro.core.api import solve_jit
 from repro.core.operators import RBFKernelSystemOperator
-from repro.core.solvers import cg_jit
 from repro.gp.kernels import DenseMatvec, RBFKernel
 from repro.runtime import spans
 
@@ -153,11 +152,7 @@ def laplace_gpc(
     y: jnp.ndarray,
     kernel: RBFKernel,
     *,
-    solver: str = "defcg",
-    solver_tol: float = 1e-5,
-    solver_maxiter: int = 2000,
-    recycle: Optional[RecycleManager] = None,
-    spec: Optional[SolveSpec] = None,
+    spec: Optional[SolveSpec],
     precond_key=None,
     newton_tol: float = 1.0,
     max_newton: int = 30,
@@ -171,12 +166,11 @@ def laplace_gpc(
     """Find the Laplace mode f̂ of GP classification by Newton's method.
 
     Args:
-      solver: "cholesky" | "cg" | "defcg" (ignored when ``spec`` given).
-      recycle: RecycleManager for solver="defcg" (created if None).
-      spec: a :class:`repro.core.SolveSpec` — the front-door path: every
-        Newton system is solved by ``repro.core.solve`` with a
-        :class:`RecycleState` carried across iterations (one jitted
-        computation per solve, no host-driven manager) and the spec's
+      spec: required.  ``None`` solves every Newton system exactly by a
+        dense Cholesky factorization of ``A`` (the paper's cubic
+        baseline).  A :class:`repro.core.SolveSpec` solves each by
+        ``repro.core.solve`` with a :class:`RecycleState` carried across
+        iterations (one jitted computation per solve) and the spec's
         preconditioner strategy applied.  ``precond="jacobi"`` builds
         ``diag(A) = 1 + h·k(x,x)`` per iteration; ``precond="nystrom"``
         sketches the INVARIANT kernel ``K ≈ UΛUᵀ`` once
@@ -192,7 +186,7 @@ def laplace_gpc(
         the effective ``M⁻¹A`` geometry.
       precond_key: PRNG key for ``spec.precond="nystrom"``.
       newton_tol: stop when ΔΨ < newton_tol (paper used ΔΨ < 1).
-      k_dense: pre-materialized K.  Required by the Cholesky path (built
+      k_dense: pre-materialized K.  Used by the Cholesky path (built
         here if absent).  If ``dense_matvec=True`` the iterative solvers
         also use it (2n² flops/matvec — the paper's own setup, where K is
         formed once per hyperparameter setting); otherwise they use the
@@ -230,27 +224,24 @@ def laplace_gpc(
     """
     n = x.shape[0]
     f = jnp.zeros(n, x.dtype)
-    if spec is not None:
-        if spec.precond == "custom":
-            raise ValueError(
-                "laplace_gpc builds the preconditioner itself and has no M "
-                "parameter — use spec.precond='jacobi'/'nystrom'/'none', or "
-                "drive repro.core.solve directly for a custom M"
-            )
-        solver = "spec"
-    if mesh is not None and (solver != "spec" or dense_matvec):
+    if spec is not None and spec.precond == "custom":
+        raise ValueError(
+            "laplace_gpc builds the preconditioner itself and has no M "
+            "parameter — use spec.precond='jacobi'/'nystrom'/'none', or "
+            "drive repro.core.solve directly for a custom M"
+        )
+    if mesh is not None and (spec is None or dense_matvec):
         raise ValueError(
             "laplace_gpc(mesh=) solves through the sharded front door over "
-            "the fused Gram kernel: pass spec= and leave dense_matvec off"
+            "the fused Gram kernel: pass a SolveSpec and leave dense_matvec "
+            "off"
         )
-    if (solver == "cholesky" or dense_matvec) and k_dense is None:
+    if (spec is None or dense_matvec) and k_dense is None:
         k_dense = kernel.gram(x)
     if dense_matvec:
         k_mv = DenseMatvec(k_dense)
     elif mesh is None:
         k_mv = kernel.matvec_fn(x, impl=impl, block=block)
-    if solver == "defcg" and recycle is None:
-        recycle = RecycleManager(k=8, ell=12, tol=solver_tol, maxiter=solver_maxiter)
     solve_state = None  # RecycleState carried across Newton systems
     k_sketch = None  # once-per-call Nyström sketch (U, lam) of K
     sketch_matvecs = 0
@@ -258,7 +249,7 @@ def laplace_gpc(
     def solve(sqrt_h, b, x_prev):
         """One Newton system's solve: ``(x, info, rung)``."""
         nonlocal solve_state, k_sketch, sketch_matvecs
-        if solver == "cholesky":
+        if spec is None:
             amat = (
                 jnp.eye(n, dtype=x.dtype)
                 + sqrt_h[:, None] * k_dense * sqrt_h[None, :]
@@ -272,54 +263,38 @@ def laplace_gpc(
             a_op = RBFKernelSystemOperator(
                 x, sqrt_h, kernel.theta, kernel.lengthscale, block, impl
             )
-        if solver == "spec":
-            M = None
-            if spec.precond == "jacobi":
-                # diag(A) = 1 + h_i k(x_i, x_i) — exact, host-free.
-                diag_k = (
-                    jnp.diag(k_dense)
-                    if dense_matvec
-                    else jnp.full(n, kernel.theta**2, x.dtype)
+        M = None
+        if spec.precond == "jacobi":
+            # diag(A) = 1 + h_i k(x_i, x_i) — exact, host-free.
+            diag_k = (
+                jnp.diag(k_dense)
+                if dense_matvec
+                else jnp.full(n, kernel.theta**2, x.dtype)
+            )
+            M = jacobi(1.0 + sqrt_h * sqrt_h * diag_k)
+        elif spec.precond == "nystrom":
+            if k_sketch is None:
+                key = (
+                    precond_key
+                    if precond_key is not None
+                    else jax.random.PRNGKey(0)
                 )
-                M = jacobi(1.0 + sqrt_h * sqrt_h * diag_k)
-            elif spec.precond == "nystrom":
-                if k_sketch is None:
-                    key = (
-                        precond_key
-                        if precond_key is not None
-                        else jax.random.PRNGKey(0)
-                    )
-                    k_sketch = randomized_nystrom(
-                        k_mv,
-                        jnp.zeros(n, x.dtype),
-                        rank=spec.precond_rank,
-                        key=key,
-                    )
-                    sketch_matvecs = spec.precond_rank + 8
-                M = kernel_nystrom_preconditioner(
-                    k_sketch[0], k_sketch[1], sqrt_h
+                k_sketch = randomized_nystrom(
+                    k_mv,
+                    jnp.zeros(n, x.dtype),
+                    rank=spec.precond_rank,
+                    key=key,
                 )
-            res = solve_jit(
-                a_op, b, spec, solve_state, x0=x_prev, M=M,
-                record_residuals=record_residuals, mesh=mesh,
+                sketch_matvecs = spec.precond_rank + 8
+            M = kernel_nystrom_preconditioner(
+                k_sketch[0], k_sketch[1], sqrt_h
             )
-            solve_state = res.state
-            return res.x, res.info, res.report.rung
-        if solver == "cg":
-            res = cg_jit(
-                a_op, b, x_prev,
-                tol=solver_tol, maxiter=solver_maxiter,
-                record_residuals=record_residuals,
-            )
-        elif solver == "defcg":
-            res = recycle.solve(
-                a_op, b, x_prev,
-                tol=solver_tol, maxiter=solver_maxiter,
-                record_residuals=record_residuals,
-            )
-        else:
-            raise ValueError(f"unknown solver={solver!r}")
-        return res.x, res.info, 0
+        res = solve_jit(
+            a_op, b, spec, solve_state, x0=x_prev, M=M,
+            record_residuals=record_residuals, mesh=mesh,
+        )
+        solve_state = res.state
+        return res.x, res.info, res.report.rung
 
     trace = NewtonTrace()
     psi_prev = np.asarray(-np.inf, x.dtype)

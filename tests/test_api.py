@@ -13,8 +13,8 @@ Five layers of checks:
   3. ``solve_batch``: B vmapped tenants bit-match B sequential ``solve``
      calls (per-tenant masks freeze finished lanes), and the whole batch
      traces to one XLA computation with no host syncs;
-  4. seed-time validation of ``RecycleManager.seed`` (host-side error
-     instead of a mid-solve XLA shape failure);
+  4. validation of a seeded ``RecycleState`` by ``solve`` (host-side
+     error instead of a mid-solve XLA shape failure);
   5. the paper-level claim: Nyström-preconditioned def-CG (invariant-K
      sketch + per-system Woodbury) beats unpreconditioned def-CG in
      matvecs on the GP Laplace Newton sequence.
@@ -30,7 +30,6 @@ import pytest
 from repro.checkpoint import restore_pytree, save_pytree
 from repro.core import (
     DEFAULT_WAW_JITTER,
-    RecycleManager,
     RecycleState,
     SolveSpec,
     cg,
@@ -60,15 +59,22 @@ def _spd_problem(n=64, cond=1e3, seed=1, row_scale=0.8):
 class TestSolveSpec:
     def test_waw_jitter_single_default(self):
         """Satellite: ONE waw_jitter default, carried by the spec and
-        shared by defcg / the manager / the sequence engine."""
+        shared by defcg and the sequence engine."""
         import inspect
+
+        from repro.core import recycle as recycle_mod
 
         assert SolveSpec().waw_jitter == DEFAULT_WAW_JITTER == 1e-12
         assert (
             inspect.signature(defcg).parameters["waw_jitter"].default
             == DEFAULT_WAW_JITTER
         )
-        assert RecycleManager(k=4, ell=8).waw_jitter == DEFAULT_WAW_JITTER
+        assert (
+            inspect.signature(recycle_mod.solve_sequence)
+            .parameters["waw_jitter"]
+            .default
+            == DEFAULT_WAW_JITTER
+        )
 
     def test_validation(self):
         with pytest.raises(ValueError, match="method"):
@@ -151,17 +157,6 @@ class TestPreconditionedDefCGParity:
         np.testing.assert_allclose(
             np.asarray(r_cg.x), np.asarray(r_def.x), rtol=1e-9, atol=1e-10
         )
-
-
-def _solve_args(n=64, cond=1e3, seed=2):
-    rng = np.random.default_rng(seed)
-    A0, _, _ = make_spd(n, cond, rng)
-    s = np.logspace(0, 1.5, n)
-    return (
-        jnp.asarray(A0 * np.outer(s, s)),
-        jnp.asarray(rng.standard_normal(n)),
-        rng,
-    )
 
 
 def _drifting_mats(n=96, k=8, num=4, seed=11, drift=0.01):
@@ -261,25 +256,6 @@ class TestSolveFrontDoor:
         assert np.asarray(seq.info.converged).all()
         assert seq.state.theta.shape == (4,)
         assert int(seq.state.systems_solved) == 2
-
-    def test_cg_jit_accepts_closure_and_pytree_preconditioners(self):
-        """cg_jit keeps working with a bare-closure M (static fallback)
-        AND with registered pytree-node preconditioners (traced)."""
-        from repro.core import jacobi
-        from repro.core.solvers import cg_jit
-
-        A, b, _ = _solve_args()
-        d = jnp.diag(A)
-        closure = lambda r: r / d  # noqa: E731
-        r1 = cg_jit(from_matrix(A), b, tol=1e-10, maxiter=2000, M=closure)
-        r2 = cg_jit(from_matrix(A), b, tol=1e-10, maxiter=2000, M=jacobi(d))
-        assert bool(r1.info.converged) and bool(r2.info.converged)
-        # static path constant-folds d; traced path streams it — same
-        # math, last-bit rounding may shift the stop by one iteration
-        assert abs(int(r1.info.iterations) - int(r2.info.iterations)) <= 1
-        np.testing.assert_allclose(
-            np.asarray(r1.x), np.asarray(r2.x), rtol=1e-7, atol=1e-9
-        )
 
     def test_legacy_w0_signature_removed(self):
         """The PR-3-era solve_sequence(systems, b, W0, AW0, k=…) shim is
@@ -447,32 +423,54 @@ class TestSolveBatch:
         )
 
 
+def _seeded(W, AW=None):
+    """A RecycleState seeded a priori with the rows ``W`` (zero AW unless
+    given): the front door's replacement for a manager's ``seed``."""
+    k = W.shape[0]
+    return RecycleState(
+        W=W, AW=jnp.zeros_like(W) if AW is None else AW,
+        theta=jnp.zeros(k), systems_solved=jnp.int32(0),
+        drift=jnp.zeros(()),
+    )
+
+
 class TestSeedValidation:
+    SPEC = SolveSpec(k=4, ell=8)
+
     def test_seed_too_many_vectors_rejected(self):
-        mgr = RecycleManager(k=4, ell=8)
-        W = jnp.asarray(np.random.default_rng(0).standard_normal((6, 32)))
-        with pytest.raises(ValueError, match="between 1 and 4"):
-            mgr.seed(W)
+        rng = np.random.default_rng(0)
+        A = from_matrix(jnp.asarray(make_spd(32, 1e2, rng)[0]))
+        b = jnp.asarray(rng.standard_normal(32))
+        W = jnp.asarray(rng.standard_normal((6, 32)))
+        with pytest.raises(ValueError, match=r"needs \(4, 32\)"):
+            solve(A, b, self.SPEC, _seeded(W))
 
     def test_seed_mismatched_aw_rejected(self):
-        mgr = RecycleManager(k=4, ell=8)
         rng = np.random.default_rng(0)
-        W = jnp.asarray(rng.standard_normal((3, 32)))
-        with pytest.raises(ValueError, match="does not match W"):
-            mgr.seed(W, jnp.asarray(rng.standard_normal((3, 16))))
-        with pytest.raises(ValueError, match="structure"):
-            mgr.seed(W, {"a": jnp.asarray(rng.standard_normal((3, 32)))})
+        A = from_matrix(jnp.asarray(make_spd(32, 1e2, rng)[0]))
+        b = jnp.asarray(rng.standard_normal(32))
+        W = jnp.asarray(rng.standard_normal((4, 32)))
+        with pytest.raises(ValueError, match="shaped like state.W"):
+            solve(A, b, self.SPEC,
+                  _seeded(W, jnp.asarray(rng.standard_normal((4, 16)))))
+        with pytest.raises(ValueError, match="shaped like state.W"):
+            solve(A, b, self.SPEC,
+                  _seeded(W, {"a": jnp.asarray(rng.standard_normal((4, 32)))}))
 
     def test_valid_seed_still_works(self):
         rng = np.random.default_rng(3)
         A, _, q = make_spd(64, 1e4, rng)
         A = jnp.asarray(A)
         W = jnp.asarray(q[:, -4:].T)
-        mgr = RecycleManager(k=4, ell=8, tol=1e-8, maxiter=3000)
-        mgr.seed(W)
-        res = mgr.solve(from_matrix(A), jnp.asarray(rng.standard_normal(64)))
+        spec = SolveSpec(k=4, ell=8, tol=1e-8, maxiter=3000)
+        res = solve(from_matrix(A), jnp.asarray(rng.standard_normal(64)),
+                    spec, _seeded(W))
         assert bool(res.info.converged)
-        assert mgr.AW is not None
+        # the zero seed AW was refreshed: the carried AW is A·W
+        np.testing.assert_allclose(
+            np.asarray(res.state.AW), np.asarray(res.state.W @ A),
+            rtol=0, atol=1e-8 * float(jnp.abs(A).max()),
+        )
 
 
 class TestLaplaceNystromPrecondition:

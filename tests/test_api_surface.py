@@ -60,14 +60,11 @@ EXPECTED_CORE_ALL = sorted(
         "randomized_nystrom",
         # recycling
         "MAX_RECOVERY_RUNGS",
-        "RecycleManager",
         "RecycleState",
         "SequenceResult",
         "harmonic_ritz",
         "harmonic_ritz_flat",
         "random_orthonormal_basis",
-        "recycled_solve_jit",
-        "solve_sequence_jit",
         # solvers
         "DEFAULT_WAW_JITTER",
         "CGResult",
@@ -148,7 +145,7 @@ def test_waw_jitter_never_forks():
     """The unified default is exactly 1e-12 everywhere it surfaces."""
     import inspect
 
-    from repro.core import RecycleManager, defcg
+    from repro.core import defcg
     from repro.core import recycle as recycle_mod
 
     assert DEFAULT_WAW_JITTER == 1e-12
@@ -163,4 +160,3 @@ def test_waw_jitter_never_forks():
         .default
         == DEFAULT_WAW_JITTER
     )
-    assert RecycleManager(k=2, ell=4).waw_jitter == DEFAULT_WAW_JITTER

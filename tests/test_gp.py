@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import RecycleManager
+from repro.core import SolveSpec
 from repro.data import make_infinite_digits
 from repro.gp import RBFKernel, laplace_gpc, subset_gpc
 
@@ -28,17 +28,26 @@ def digits():
 @pytest.fixture(scope="module")
 def solutions(digits):
     x, y = digits
-    chol = laplace_gpc(x, y, KERNEL, solver="cholesky", newton_tol=1e-2)
-    cg_r = laplace_gpc(x, y, KERNEL, solver="cg", solver_tol=1e-6, newton_tol=1e-2)
-    mgr = RecycleManager(k=8, ell=12, tol=1e-6, maxiter=2000)
+    chol = laplace_gpc(x, y, KERNEL, spec=None, newton_tol=1e-2)
+    cg_r = laplace_gpc(
+        x, y, KERNEL, spec=SolveSpec(method="cg", tol=1e-6), newton_tol=1e-2
+    )
     def_r = laplace_gpc(
-        x, y, KERNEL, solver="defcg", recycle=mgr,
-        solver_tol=1e-6, newton_tol=1e-2,
+        x, y, KERNEL,
+        spec=SolveSpec(method="defcg", k=8, ell=12, tol=1e-6),
+        newton_tol=1e-2,
     )
     return chol, cg_r, def_r
 
 
 class TestLaplaceGPC:
+    def test_spec_is_required(self, digits):
+        """No default solver: a fit names its SolveSpec, or None for the
+        dense Cholesky solve."""
+        x, y = digits
+        with pytest.raises(TypeError, match="spec"):
+            laplace_gpc(x, y, KERNEL, newton_tol=1e-2)
+
     def test_newton_monotone(self, solutions):
         chol, _, _ = solutions
         psi = chol.trace.psi

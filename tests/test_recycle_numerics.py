@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import RecycleManager, cg, defcg, from_matrix, harmonic_ritz
+from repro.core import SolveSpec, cg, defcg, from_matrix, harmonic_ritz, solve
 from repro.core import pytree as pt
 
 
@@ -52,10 +52,10 @@ class TestRitzNumerics:
             [np.linspace(1.0, 10.0, n - k), np.logspace(3, 5, k)]
         )
         A = jnp.asarray((q * eigs) @ q.T)
-        mgr = RecycleManager(k=k, ell=3 * k, tol=1e-5, maxiter=10000)
-        mgr.solve(from_matrix(A), jnp.asarray(rng.standard_normal(n)))
+        spec = SolveSpec(k=k, ell=3 * k, tol=1e-5, maxiter=10000)
+        first = solve(from_matrix(A), jnp.asarray(rng.standard_normal(n)), spec)
         b2 = jnp.asarray(rng.standard_normal(n))
-        rec = mgr.solve(from_matrix(A), b2, reuse_aw=True)
+        rec = solve(from_matrix(A), b2, spec, first.state)
         fresh = cg(from_matrix(A), b2, tol=1e-5, maxiter=10000)
         bound = 1.5 * 0.5 * np.sqrt(10.0) * np.log(2.0 / 1e-5)
         assert int(rec.info.iterations) <= bound

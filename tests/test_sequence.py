@@ -14,6 +14,8 @@ Four layers of checks:
      for every concrete operator (one fused pass ≡ k applications).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +29,8 @@ from repro.core import (
     from_matrix,
     harmonic_ritz,
     harmonic_ritz_flat,
-    solve_sequence_jit,
+    SolveSpec,
+    solve_sequence,
 )
 from repro.core import pytree as pt
 from tests.conftest import make_spd
@@ -115,14 +118,14 @@ def _drifting_sequence(n=96, k=8, num=5, seed=11, drift=0.01):
     return jnp.asarray(np.stack(mats)), jnp.asarray(np.stack(bs))
 
 
+SPEC = SolveSpec(k=8, ell=12, tol=1e-8, maxiter=5000)
+
+
 class TestSolveSequence:
     def test_drifting_sequence_iterations_fall(self):
         """Paper Fig. 2: recycling must cut iterations after system 1."""
         mats, bs = _drifting_sequence()
-        seq = solve_sequence_jit(
-            mats, bs, k=8, ell=12, make_operator=from_matrix,
-            tol=1e-8, maxiter=5000,
-        )
+        seq = solve_sequence(mats, bs, SPEC, make_operator=from_matrix)
         iters = np.asarray(seq.info.iterations)
         cg_iters = [
             int(
@@ -146,10 +149,7 @@ class TestSolveSequence:
         except the cold bootstrap system, whose all-zero basis needs (and
         is charged) no refresh."""
         mats, bs = _drifting_sequence(num=3)
-        seq = solve_sequence_jit(
-            mats, bs, k=8, ell=12, make_operator=from_matrix,
-            tol=1e-8, maxiter=5000,
-        )
+        seq = solve_sequence(mats, bs, SPEC, make_operator=from_matrix)
         np.testing.assert_array_equal(
             np.asarray(seq.info.matvecs),
             np.asarray(seq.info.iterations) + 1 + np.array([0, 8, 8]),
@@ -175,12 +175,12 @@ class TestSolveSequence:
         matvecs = iterations + 2 (r₀ shortcut + one true-matvec rederive).
 
         (Under operator drift, stale deflation can destabilize the
-        conjugacy recurrence outright — RecycleManager's breakdown
-        fallback covers that host-side; see its docstring.)"""
+        conjugacy recurrence outright — the recovery ladder and the
+        WindowedRecombine drift guard cover that.)"""
         mats, bs = _drifting_sequence(num=4, seed=29, drift=0.0)
-        seq = solve_sequence_jit(
-            mats, bs, k=8, ell=12, make_operator=from_matrix,
-            tol=1e-8, maxiter=5000, refresh_aw="stale",
+        seq = solve_sequence(
+            mats, bs, dataclasses.replace(SPEC, refresh_aw="stale"),
+            make_operator=from_matrix,
         )
         for i in range(mats.shape[0]):
             np.testing.assert_allclose(
@@ -244,28 +244,23 @@ class TestSolveSequence:
         b = rng.standard_normal(n)
         mats = jnp.asarray(np.stack([A] * 3))
         bs = jnp.asarray(np.stack([b] * 3))
-        seq = solve_sequence_jit(
-            mats, bs, k=6, ell=12, make_operator=from_matrix,
-            tol=1e-8, maxiter=2000, carry_x=True,
+        seq = solve_sequence(
+            mats, bs, SolveSpec(k=6, ell=12, tol=1e-8, maxiter=2000),
+            make_operator=from_matrix, carry_x=True,
         )
         iters = np.asarray(seq.info.iterations)
         assert iters[1] <= 2 and iters[2] <= 2
 
     def test_seeding_from_previous_result(self):
-        """The returned (W, AW) seeds a follow-up call (sequence resume)."""
+        """The returned state seeds a follow-up call (sequence resume)."""
         mats, bs = _drifting_sequence(num=4, seed=41)
-        first = solve_sequence_jit(
-            mats[:2], bs[:2], k=8, ell=12, make_operator=from_matrix,
-            tol=1e-8, maxiter=5000,
+        first = solve_sequence(
+            mats[:2], bs[:2], SPEC, make_operator=from_matrix
         )
-        resumed = solve_sequence_jit(
-            mats[2:], bs[2:], first.W, first.AW,
-            k=8, ell=12, make_operator=from_matrix, tol=1e-8, maxiter=5000,
+        resumed = solve_sequence(
+            mats[2:], bs[2:], SPEC, first.state, make_operator=from_matrix
         )
-        cold = solve_sequence_jit(
-            mats[2:], bs[2:], k=8, ell=12, make_operator=from_matrix,
-            tol=1e-8, maxiter=5000,
-        )
+        cold = solve_sequence(mats[2:], bs[2:], SPEC, make_operator=from_matrix)
         # the seeded run's FIRST system already benefits from recycling
         assert int(resumed.info.iterations[0]) < int(cold.info.iterations[0])
 

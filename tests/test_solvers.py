@@ -16,7 +16,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (
-    RecycleManager,
+    RecycleState,
+    SolveSpec,
     cg,
     cholesky_solve,
     defcg,
@@ -26,6 +27,7 @@ from repro.core import (
     random_orthonormal_basis,
     randomized_nystrom,
     nystrom_preconditioner,
+    solve,
 )
 from repro.core import pytree as pt
 from tests.conftest import make_spd
@@ -142,25 +144,30 @@ class TestDefCG:
         # Line 3 of Alg 1: x0 correction zeroes Wᵀr0 (checked indirectly:
         # solving the same system twice with recycling is near-free).
         A, b, _, _ = _solve_setup(n=64, cond=1e4)
-        mgr = RecycleManager(k=8, ell=16, tol=1e-10, maxiter=1000)
-        first = mgr.solve(from_matrix(A), b)
-        second = mgr.solve(from_matrix(A), b, x0=first.x)
+        spec = SolveSpec(k=8, ell=16, tol=1e-10, maxiter=1000)
+        first = solve(from_matrix(A), b, spec)
+        second = solve(from_matrix(A), b, spec, first.state, x0=first.x)
         assert int(second.info.iterations) <= 2
 
-    def test_seeded_basis_without_aw_reuse_aw(self):
-        """Regression: seed(W) with no AW + reuse_aw=True on the first
-        solve must compute AW (nothing to reuse yet), not crash raveling
-        None in the refresh."""
+    def test_seeded_basis_without_aw_is_refreshed(self):
+        """An a-priori basis seeded as a RecycleState with zero AW: the
+        exact refresh computes its A-products before the first solve,
+        deflates with them, and charges the k matvecs."""
         A, b, eigs, q = _solve_setup(n=96, cond=1e5, seed=17)
         k = 8
-        W = pt.basis_from_vectors(
-            [jnp.asarray(q[:, -(i + 1)]) for i in range(k)]
+        W = jnp.asarray(q[:, -k:].T)  # top-k eigenvectors as (k, n) rows
+        seeded = RecycleState(
+            W=W, AW=jnp.zeros_like(W), theta=jnp.zeros(k),
+            systems_solved=jnp.int32(0), drift=jnp.zeros(()),
         )
-        mgr = RecycleManager(k=k, ell=12, tol=1e-8, maxiter=3000)
-        mgr.seed(W)  # a-priori seeding, no A-products
-        res = mgr.solve(from_matrix(A), b, reuse_aw=True)
+        spec = SolveSpec(k=k, ell=12, tol=1e-8, maxiter=3000)
+        res = solve(from_matrix(A), b, spec, seeded)
         assert bool(res.info.converged)
-        assert mgr.AW is not None
+        # the carried AW is A·W of the extracted basis, not the zero seed
+        np.testing.assert_allclose(
+            np.asarray(res.state.AW), np.asarray(res.state.W @ A),
+            rtol=0, atol=1e-8 * float(np.abs(eigs).max()),
+        )
         # exact top-k deflation: clearly beats fresh CG, and the k AW
         # matvecs are charged
         fresh = cg(from_matrix(A), b, tol=1e-8, maxiter=3000)
@@ -169,20 +176,19 @@ class TestDefCG:
 
     def test_zero_iteration_solve_keeps_basis_state(self):
         """Regression: a 0-iteration solve (exact x0) records nothing and
-        must leave the manager's basis untouched — in particular a None
-        basis must not become a phantom all-zero basis that gets charged
-        k refresh matvecs on every later system."""
+        must leave a cold state cold — an all-zero basis, not a phantom
+        one that gets charged k refresh matvecs on every later system."""
         A, b, _, _ = _solve_setup(n=48, cond=1e2)
         x_exact = jnp.linalg.solve(A, b)
-        mgr = RecycleManager(k=4, ell=8, tol=1e-6, maxiter=500)
-        res = mgr.solve(from_matrix(A), b, x0=x_exact)
+        spec = SolveSpec(k=4, ell=8, tol=1e-6, maxiter=500)
+        res = solve(from_matrix(A), b, spec, x0=x_exact)
         assert int(res.info.iterations) == 0
-        assert mgr.W is None
+        assert not np.any(np.asarray(res.state.W))
         # the next solve runs as a plain first system: no refresh charge
-        res2 = mgr.solve(from_matrix(A), b)
+        res2 = solve(from_matrix(A), b, spec, res.state)
         plain = defcg(from_matrix(A), b, ell=8, tol=1e-6, maxiter=500)
         assert int(res2.info.matvecs) == int(plain.info.matvecs)
-        assert mgr.W is not None  # and recycling is bootstrapped now
+        assert np.any(np.asarray(res2.state.W))  # recycling bootstrapped
 
     def test_recycling_drifting_sequence(self):
         # The paper's setting: a slowly drifting SPD sequence — recycling
@@ -194,7 +200,8 @@ class TestDefCG:
             [np.linspace(1.0, 5.0, n - k), np.logspace(3.0, 4.5, k)]
         )
         base = (q * eigs) @ q.T
-        mgr = RecycleManager(k=k, ell=ell, tol=1e-8, maxiter=5000)
+        spec = SolveSpec(k=k, ell=ell, tol=1e-8, maxiter=5000)
+        state = None
         cg_iters, defcg_iters = [], []
         x_prev = None
         for i in range(5):
@@ -202,8 +209,8 @@ class TestDefCG:
             Ai = jnp.asarray(base + pert @ pert.T)  # SPD drift
             bi = jnp.asarray(rng.standard_normal(n))
             cg_iters.append(int(cg(from_matrix(Ai), bi, tol=1e-8, maxiter=5000).info.iterations))
-            res = mgr.solve(from_matrix(Ai), bi, x0=x_prev)
-            x_prev = res.x
+            res = solve(from_matrix(Ai), bi, spec, state, x0=x_prev)
+            state, x_prev = res.state, res.x
             defcg_iters.append(int(res.info.iterations))
             np.testing.assert_allclose(
                 Ai @ res.x, bi, rtol=0, atol=1e-7 * np.linalg.norm(bi)
@@ -218,33 +225,36 @@ class TestDefCG:
         assert bool(res.info.breakdown) or not bool(res.info.converged)
 
     def test_fallback_matvec_accounting(self):
-        """Regression: when a poisoned basis forces the clean re-solve,
-        the reported matvecs must be the TRUE total — refresh + failed
-        attempt + fallback — not just the fallback with the discarded
-        basis's refresh cost stapled on."""
+        """Regression: when a poisoned basis sends the solve down the
+        recovery ladder, the reported matvecs must be the TRUE total —
+        every refresh and every attempt — not just the last attempt."""
         A, b, _, _ = _solve_setup(n=64, cond=1e4)
         k, ell, maxiter = 4, 8, 6  # maxiter too small to converge
         W = random_orthonormal_basis(jax.random.PRNGKey(0), b, k)
+        seeded = RecycleState(
+            W=W, AW=jnp.zeros_like(W), theta=jnp.zeros(k),
+            systems_solved=jnp.int32(0), drift=jnp.zeros(()),
+        )
+        spec = SolveSpec(k=k, ell=ell, tol=1e-10, maxiter=maxiter)
+        res = solve(from_matrix(A), b, spec, seeded)
+        assert not bool(res.info.converged)  # every attempt hits maxiter
+        # rung 1 (refresh AW, redo) and rung 2 (drop the basis) ran; rung
+        # 3 (shifted plain CG) needs a breakdown
+        assert int(res.report.rung) == 2
+        assert np.any(np.asarray(res.state.W))  # rung 2 re-bootstrapped
 
-        mgr = RecycleManager(k=k, ell=ell, tol=1e-10, maxiter=maxiter)
-        mgr.seed(W)
-        res = mgr.solve(from_matrix(A), b)
-        assert not bool(res.info.converged)  # both attempts hit maxiter
-        assert mgr.W is not None  # fallback still re-bootstrapped a basis
-
-        # Reference costs of the two attempts, run in isolation.
+        # Reference costs of the attempts, run in isolation.
         AW = pt.basis_map_vectors(from_matrix(A), W)
         failed = defcg(
             from_matrix(A), b, W=W, AW=AW, ell=ell,
-            tol=1e-10, maxiter=maxiter, waw_jitter=mgr.waw_jitter,
+            tol=1e-10, maxiter=maxiter, waw_jitter=spec.waw_jitter,
         )
         fallback = defcg(
             from_matrix(A), b, ell=ell, tol=1e-10, maxiter=maxiter
         )
         expected = (
-            k  # refresh of the (discarded) basis — it was still computed
-            + int(failed.info.matvecs)
-            + int(fallback.info.matvecs)
+            2 * (k + int(failed.info.matvecs))  # first attempt and rung 1
+            + int(fallback.info.matvecs)  # rung 2, basis dropped
         )
         assert int(res.info.matvecs) == expected
 

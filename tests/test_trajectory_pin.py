@@ -1,26 +1,52 @@
-"""Bit-identity pins for the engine-harness refactor (ISSUE 9).
+"""Trajectory pins for the def-CG front door and the engine harness.
 
-The harness extraction (``core/engine.py``) must be a *relocation* of the
-loop machinery, not a rewrite: ``cg`` and ``defcg`` re-seated on the
-engine have to reproduce the pre-refactor iterate trajectories BIT FOR
-BIT.  This module pins them against golden data captured from the
-pre-refactor solvers on a fig2-style GP Newton trace:
+The single-system and sequence front doors (``solve`` /
+``solve_sequence``) and the loop harness under them (``core/engine.py``)
+are pinned against golden data captured on a fig2-style GP Newton trace:
 
   * plain CG on the first Newton system — final iterate, iteration count,
-    matvec count, status;
+    matvec count, status, residual norm;
   * the def-CG sequence front door over the drifting trace — per-system
     solutions, residual norms, iteration/matvec counts, statuses, Ritz
     values, recovery rungs, and the final recycled basis;
   * a recovery-ladder case (indefinite operator, ladder armed) — the
-    rung taken, terminal status, and honest matvec total.
+    rung taken, terminal status, honest matvec total and the adopted
+    iterate.
 
 Regenerate the golden file ONLY when a deliberate numeric change is
-intended (document it in the PR):
+intended (document it in the change):
 
     PYTHONPATH=src python tests/test_trajectory_pin.py
 
-Comparisons are exact (``assert_array_equal`` on raw float bits) — any
-reordering of the loop-body arithmetic shows up here.
+Every integer field (iterations, matvecs, status, rung) must match
+EXACTLY: a lost or gained iteration, a different stop or a different rung
+is a change of algorithm, not of rounding.  The floats are compared to
+bounds derived from each scenario's own ``tol`` (1e-10 CG, 1e-9
+sequence, 1e-8 ladder), because a compiler may reassociate the loop
+body's sums and move the last bits:
+
+  * ``x`` of the CG and sequence solves: ``‖x − x_golden‖ ≤ 2·tol·‖b‖``.
+    Both runs stop with ``‖r‖ ≤ tol·‖b‖``, and ``x − x_golden =
+    A⁻¹(r_golden − r)``; the operator is ``I + H½KH½``, whose eigenvalues
+    are ≥ 1, so ``‖A⁻¹‖ ≤ 1``.
+  * the sequence's final ``W`` and ``AW`` (rows aligned in sign: a Ritz
+    vector's sign is arbitrary and def-CG is blind to it) and its Ritz
+    values θ: relative error ≤ tol (Frobenius norms).  They are built
+    from the recorded directions of solves that are only defined to tol.
+  * the ladder's adopted ``x``: relative error ≤ tol.  It is an iterate
+    of the pinned attempt after the pinned iteration count, not a
+    converged solution, so no residual bound applies; tol is the
+    resolution the scenario asks of it.
+  * residual norms: each at or below its stopping threshold
+    ``max(tol·‖b‖, atol)`` (``atol`` = 0 here), and within a factor
+    ``RESIDUAL_FACTOR`` = 2 of the golden.  Reaching tol took the CG
+    solve 26 iterations and each sequence solve 17–22, so one iteration
+    cuts the residual about 2.4–3.4×: a factor 2 is less than one
+    iteration's worth, and far above the ~1% that rounding moves it.
+
+Under JAX 0.9.0 the largest drifts from the golden are 1.2e-11 in ``x``,
+2.8e-15 in ``W``, 2.2e-14 in ``AW``, 8.5e-14 in θ (absolute) and 1.2%
+in a residual norm.
 """
 
 import os
@@ -38,6 +64,8 @@ GOLDEN = os.path.join(os.path.dirname(__file__), "golden",
 _N = 96  # GP trace size — small enough for CI, big enough to iterate
 _K, _ELL = 4, 8
 _NUM_SYSTEMS = 4
+CG_TOL, SEQ_TOL, LADDER_TOL = 1e-10, 1e-9, 1e-8
+RESIDUAL_FACTOR = 2.0
 
 
 def _fig2_newton_trace():
@@ -86,11 +114,11 @@ def _run_all():
     )
 
     kmat, sqrt_hs, bs = _fig2_newton_trace()
-    out = {}
+    out = {"b_norm": np.linalg.norm(np.asarray(bs), axis=1)}
 
     # -- plain CG on the first Newton system -----------------------------
     op0 = KernelSystemOperator(lambda v: kmat @ v, sqrt_hs[0])
-    res = cg(op0, bs[0], tol=1e-10, maxiter=600)
+    res = cg(op0, bs[0], tol=CG_TOL, maxiter=600)
     out["cg_x"] = np.asarray(res.x)
     out["cg_iterations"] = np.asarray(res.info.iterations)
     out["cg_matvecs"] = np.asarray(res.info.matvecs)
@@ -98,7 +126,7 @@ def _run_all():
     out["cg_residual_norm"] = np.asarray(res.info.residual_norm)
 
     # -- def-CG sequence over the drifting Newton trace ------------------
-    spec = SolveSpec(method="defcg", k=_K, ell=_ELL, tol=1e-9, maxiter=600)
+    spec = SolveSpec(method="defcg", k=_K, ell=_ELL, tol=SEQ_TOL, maxiter=600)
     seq = solve_sequence(
         sqrt_hs,
         bs,
@@ -119,7 +147,7 @@ def _run_all():
 
     # -- recovery-ladder behavior on an indefinite operator --------------
     mat, b = _indefinite_problem()
-    bad_spec = SolveSpec(method="defcg", k=3, ell=6, tol=1e-8, maxiter=300,
+    bad_spec = SolveSpec(method="defcg", k=3, ell=6, tol=LADDER_TOL, maxiter=300,
                          recovery_rungs=3, recovery_shift=1e-6)
     # A warm basis forces the deflated path; the indefinite spectrum
     # breaks it, so the ladder must climb — pin the rung it lands on.
@@ -146,23 +174,50 @@ def current():
     return _run_all()
 
 
-def test_cg_trajectory_bit_identical(golden, current):
-    for key in ("cg_x", "cg_iterations", "cg_matvecs", "cg_status",
-                "cg_residual_norm"):
+def _assert_exact(current, golden, keys):
+    for key in keys:
         np.testing.assert_array_equal(current[key], golden[key], err_msg=key)
 
 
-def test_defcg_sequence_bit_identical(golden, current):
-    for key in ("seq_x", "seq_iterations", "seq_matvecs", "seq_status",
-                "seq_residual_norm", "seq_theta", "seq_rung",
-                "seq_final_W", "seq_final_AW"):
-        np.testing.assert_array_equal(current[key], golden[key], err_msg=key)
+def _assert_residuals(current, golden, key, tol, b_norm):
+    got, want = current[key], golden[key]
+    assert np.all(got <= tol * b_norm), (key, got, tol * b_norm)
+    ratio = got / want
+    assert np.all((ratio <= RESIDUAL_FACTOR) & (ratio >= 1 / RESIDUAL_FACTOR)), (
+        key, ratio)
 
 
-def test_recovery_ladder_bit_identical(golden, current):
-    for key in ("ladder_status", "ladder_rung", "ladder_matvecs",
-                "ladder_x"):
-        np.testing.assert_array_equal(current[key], golden[key], err_msg=key)
+def _rel(got, want):
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+def test_cg_trajectory_pinned(golden, current):
+    _assert_exact(current, golden, ("cg_iterations", "cg_matvecs", "cg_status"))
+    b_norm = current["b_norm"][0]
+    assert np.linalg.norm(current["cg_x"] - golden["cg_x"]) <= 2 * CG_TOL * b_norm
+    _assert_residuals(current, golden, "cg_residual_norm", CG_TOL, b_norm)
+
+
+def test_defcg_sequence_pinned(golden, current):
+    _assert_exact(current, golden, ("seq_iterations", "seq_matvecs",
+                                    "seq_status", "seq_rung"))
+    b_norm = current["b_norm"]
+    x_err = np.linalg.norm(current["seq_x"] - golden["seq_x"], axis=1)
+    assert np.all(x_err <= 2 * SEQ_TOL * b_norm), (x_err, b_norm)
+    _assert_residuals(current, golden, "seq_residual_norm", SEQ_TOL, b_norm)
+    # Align each recycled vector's sign with the golden's.
+    sign = np.where(np.sum(current["seq_final_W"] * golden["seq_final_W"],
+                           axis=1, keepdims=True) < 0, -1.0, 1.0)
+    for key, got in (("seq_final_W", sign * current["seq_final_W"]),
+                     ("seq_final_AW", sign * current["seq_final_AW"]),
+                     ("seq_theta", current["seq_theta"])):
+        assert _rel(got, golden[key]) <= SEQ_TOL, key
+
+
+def test_recovery_ladder_pinned(golden, current):
+    _assert_exact(current, golden, ("ladder_status", "ladder_rung",
+                                    "ladder_matvecs"))
+    assert _rel(current["ladder_x"], golden["ladder_x"]) <= LADDER_TOL
 
 
 if __name__ == "__main__":
