@@ -27,6 +27,16 @@ def _tol(dtype, scale=1.0):
 # ---------------------------------------------------------------------------
 
 
+def _assert_matches_oracle(got, x, v, theta, ls):
+    """``got`` against ``K(X, X) @ v`` in float64, relative to its largest
+    entry: the kernels' f32 distance expansion is the thing under test."""
+    want = np.asarray(ref.rbf_matvec(
+        x.astype(jnp.float64), v.astype(jnp.float64), theta, ls))
+    scale = max(1.0, np.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got) / scale, want / scale,
+                               **_tol(F32))
+
+
 class TestRBFMatvec:
     @pytest.mark.parametrize("impl", ["interpret", "chunked"])
     @pytest.mark.parametrize(
@@ -38,17 +48,36 @@ class TestRBFMatvec:
         rng = np.random.default_rng(n + d + r)
         x = jnp.asarray(rng.standard_normal((n, d)), F32)
         v = jnp.asarray(rng.standard_normal((n, r)), F32)
-        theta, ls = 1.3, 2.1
-        # Oracle in float64 — the kernels' f32 distance expansion is the
-        # thing under test.
-        want = np.asarray(
-            ref.rbf_matvec(x.astype(jnp.float64), v.astype(jnp.float64), theta, ls)
-        )
-        got = np.asarray(
-            ops.rbf_matvec(x, v, theta, ls, impl=impl, block=64)
-        )
-        scale = max(1.0, np.abs(want).max())
-        np.testing.assert_allclose(got / scale, want / scale, **_tol(F32))
+        got = ops.rbf_matvec(x, v, 1.3, 2.1, impl=impl, block=64)
+        _assert_matches_oracle(got, x, v, 1.3, 2.1)
+
+    @pytest.mark.parametrize("r", [1, 3, R_VPU])
+    @pytest.mark.parametrize("nb", [1, 2, 3, 4, 5])
+    def test_symmetric_band_matches_oracle(self, nb, r):
+        """The square pass at r ≤ R_VPU visits each unordered pair of
+        row blocks once: nb odd, nb even with its t = nb/2 column taken
+        by half the rows, and n not a multiple of the block."""
+        n = 32 * nb - 5
+        rng = np.random.default_rng(10 * nb + r)
+        x = jnp.asarray(rng.standard_normal((n, 3)), F32)
+        v = jnp.asarray(rng.standard_normal((n, r)), F32)
+        got = ops.rbf_matvec(x, v, 1.2, 1.9, impl="interpret", block=32)
+        assert got.shape == (n, r)
+        _assert_matches_oracle(got, x, v, 1.2, 1.9)
+
+    def test_symmetric_band_applies_each_tile_both_ways(self):
+        """``(K e_b)_a == (K e_a)_b`` bit for bit: one tile gives both,
+        in the same block, across blocks, and across the t = nb/2 pairs
+        (blocks 0 and 2, 1 and 3 of four)."""
+        rng = np.random.default_rng(17)
+        x = jnp.asarray(rng.standard_normal((123, 3)), F32)
+        idx = np.array([3, 20, 40, 70, 100, 122])
+        e = jnp.zeros((123, len(idx)), F32).at[idx, np.arange(len(idx))].set(1)
+        y = np.asarray(ops.rbf_matvec(x, e, 1.0, 1.6, impl="interpret",
+                                      block=32))
+        block = y[idx]  # block[p, q] = K[idx[p], idx[q]]
+        np.testing.assert_array_equal(block, block.T)
+        assert np.all(block[~np.eye(len(idx), dtype=bool)] > 0)
 
     def test_single_vector_shape(self):
         x = jnp.ones((10, 2), F32)
@@ -95,28 +124,23 @@ class TestRBFMatvec:
         rng = np.random.default_rng(r)
         x = jnp.asarray(rng.standard_normal((300, 9)), F32)
         v = jnp.asarray(rng.standard_normal((300, r)), F32)
-        want = np.asarray(ref.rbf_matvec(
-            x.astype(jnp.float64), v.astype(jnp.float64), 1.3, 2.1))
-        got = np.asarray(
-            ops.rbf_matvec(x, v, 1.3, 2.1, impl="interpret", block=256))
-        scale = max(1.0, np.abs(want).max())
-        np.testing.assert_allclose(got / scale, want / scale, **_tol(F32))
+        got = ops.rbf_matvec(x, v, 1.3, 2.1, impl="interpret", block=256)
+        _assert_matches_oracle(got, x, v, 1.3, 2.1)
 
-    @pytest.mark.parametrize("r", [1, R_VPU + 1])
+    @pytest.mark.parametrize("r", [1, R_VPU, R_VPU + 1])
     def test_vmapped_batch_matches_oracle(self, r):
-        # The serve pool step's form: one kernel call over a batch axis.
+        # The serve pool step's form: one kernel call over a batch axis,
+        # at an odd (3) and an even (4) number of row blocks.
         rng = np.random.default_rng(30 + r)
-        xb = jnp.asarray(rng.standard_normal((3, 90, 4)), F32)
-        vb = jnp.asarray(rng.standard_normal((3, 90, r)), F32)
-        got = np.asarray(jax.vmap(
-            lambda x, v: ops.rbf_matvec(x, v, 1.1, 1.7, impl="interpret",
-                                        block=32)
-        )(xb, vb))
-        for x, v, y in zip(xb, vb, got):
-            want = np.asarray(ref.rbf_matvec(
-                x.astype(jnp.float64), v.astype(jnp.float64), 1.1, 1.7))
-            scale = max(1.0, np.abs(want).max())
-            np.testing.assert_allclose(y / scale, want / scale, **_tol(F32))
+        for n in (90, 120):
+            xb = jnp.asarray(rng.standard_normal((3, n, 4)), F32)
+            vb = jnp.asarray(rng.standard_normal((3, n, r)), F32)
+            got = jax.vmap(
+                lambda x, v: ops.rbf_matvec(x, v, 1.1, 1.7,
+                                            impl="interpret", block=32)
+            )(xb, vb)
+            for x, v, y in zip(xb, vb, got):
+                _assert_matches_oracle(y, x, v, 1.1, 1.7)
 
     @pytest.mark.parametrize("r,dots", [(1, 1), (R_VPU + 1, 2)])
     def test_kv_product_runs_on_the_vpu_for_narrow_v(self, r, dots):

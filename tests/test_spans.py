@@ -16,7 +16,7 @@ import pytest
 from repro.core import DenseMatrixOperator, SolveSpec
 from repro.gp import RBFKernel, laplace_gpc
 from repro.kernels import ops as kops
-from repro.kernels.rbf_matvec import R_VPU
+from repro.kernels.rbf_matvec import R_VPU, SYM_OUT_BYTES
 from repro.runtime import spans
 from repro.serve import SolveService
 
@@ -113,6 +113,32 @@ def test_gram_kernel_counts_its_kv_unit_on_the_open_span(r):
                    else ("gram_kv_mxu", "gram_kv_vpu"))
     assert inner.attrs[unit] == 1 and other not in inner.attrs
     assert unit not in outer.attrs
+
+
+@pytest.mark.parametrize("call", ["square", "rect", "wide", "long"])
+def test_gram_kernel_counts_its_symmetric_body_on_the_open_span(call):
+    """Only the square pass with r ≤ R_VPU whose output fits
+    ``SYM_OUT_BYTES`` takes the symmetric body and counts ``gram_sym``;
+    a rectangular call, wider V and a longer pass (traced, not run) do
+    not.  Shapes no other test traces."""
+    n = SYM_OUT_BYTES // (4 * 8) + 1 if call == "long" else 37
+    x = jnp.ones((n, 5), jnp.float32)
+    r = R_VPU + 1 if call == "wide" else 1
+    v = jnp.ones((n, r), jnp.float32)
+    with spans.span("t.gram_sym") as outer:
+        with spans.span("t.gram_sym_inner") as inner:
+            if call == "rect":
+                kops.rbf_matvec_rect(x[:19], x, v, 1.0, 1.0,
+                                     impl="interpret", block=32)
+            else:
+                jax.eval_shape(lambda x, v: kops.rbf_matvec(
+                    x, v, 1.0, 1.0, impl="interpret", block=32), x, v)
+    assert ("gram_kv_mxu" if call == "wide" else "gram_kv_vpu") in inner.attrs
+    if call == "square":
+        assert inner.attrs["gram_sym"] == 1
+    else:
+        assert "gram_sym" not in inner.attrs
+    assert "gram_sym" not in outer.attrs
 
 
 def test_spans_share_the_profiler_clock(tmp_path):

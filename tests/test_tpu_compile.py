@@ -19,6 +19,8 @@ n = 65536 over a described 2x2 mesh.
 
 import os
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +37,11 @@ from repro.core.recycle import RecycleState
 from repro.gp import laplace
 from repro.gp.kernels import GramMatvec
 from repro.kernels import ops as kops
-from repro.kernels.rbf_matvec import R_VPU
+from repro.kernels.rbf_matvec import R_VPU, SYM_OUT_BYTES
+
+# The benchmark's pass count, read from the kernel's output shape.
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench.readers import passes  # noqa: E402
 
 N, D, K, ELL = 32768, GPC.d, GPC.k, GPC.ell
 
@@ -82,6 +88,10 @@ KERNELS = {
     "rbf_matvec": (
         lambda x, v: kops.rbf_matvec(x, v, 3.0, 3.0, block=GPC.block, **_P),
         [(N, D), (N,)],
+    ),
+    "rbf_matvec_k": (
+        lambda x, v: kops.rbf_matvec(x, v, 3.0, 3.0, block=GPC.block, **_P),
+        [(N, D), (N, K)],
     ),
     "rbf_matvec_rect": (
         lambda xr, xc, v: kops.rbf_matvec_rect(
@@ -157,13 +167,27 @@ def _gram_out_shapes(hlo: str):
 
 
 def test_narrow_gram_call_writes_one_pass_per_output(one_chip):
-    """At r = 1 (the VPU body) the kernel's output is the rank-2
-    ``f32[N,8]`` block column that ``bench/readers.passes`` counts as one
-    pass, not a rank-3 array of lane partial sums."""
-    fn, shapes = KERNELS["rbf_matvec"]
+    """At r = 1 and r = k (the symmetric VPU body) the kernel's output
+    is the rank-2 ``f32[8,N]`` accumulator, V on lanes, that
+    ``bench/readers.passes`` counts as one pass: not a rank-3 array of
+    lane or pair partial sums."""
+    for name in ("rbf_matvec", "rbf_matvec_k"):
+        fn, shapes = KERNELS[name]
+        with jax.enable_x64(False):
+            hlo = _compile_text(fn, *(_f32(one_chip, *s) for s in shapes))
+        (shape,) = _gram_out_shapes(hlo)
+        assert shape == f"f32[8,{N}]" and passes(shape) == 1
+
+
+def test_square_pass_past_the_band_output_compiles_for_v5e(one_chip):
+    """Past ``SYM_OUT_BYTES`` of output (2^18 rows here) a square pass
+    at r = k takes the full grid, whose output is ``(n_pad, 8)``: the
+    symmetric body's whole-length output would overflow VMEM."""
+    fn, _ = KERNELS["rbf_matvec_k"]
+    n = 2 * SYM_OUT_BYTES // (4 * 8)
     with jax.enable_x64(False):
-        hlo = _compile_text(fn, *(_f32(one_chip, *s) for s in shapes))
-    assert _gram_out_shapes(hlo) == [f"f32[{N},8]"]
+        hlo = _compile_text(fn, _f32(one_chip, n, D), _f32(one_chip, n, K))
+    assert _gram_out_shapes(hlo) == [f"f32[{n},8]"]
 
 
 def _state_shapes(sharding, batch=()):
@@ -217,7 +241,9 @@ def test_f32_pool_step_compiles_for_v5e(one_chip, auto_is_pallas):
         )
     assert "rbf_gram_matvec" in hlo
     # One rank-3 output under vmap: a pass per slot.
-    assert set(_gram_out_shapes(hlo)) == {f"f32[{slots},{N},8]"}
+    shapes = set(_gram_out_shapes(hlo))
+    assert shapes == {f"f32[{slots},8,{N}]"}
+    assert {passes(s) for s in shapes} == {slots}
 
 
 @pytest.fixture(scope="module")
